@@ -22,6 +22,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from benchmarks.workloads import (chain_engine, counting_engine,
                                   uniform_batch, zipf_batch)
+from repro.launch.compile_cache import use_compile_cache
 
 ROWS = []
 
@@ -481,6 +482,26 @@ def bench_failover():
         "master broadcast + 64k-key reroute (no recompile)")
 
 
+def _cpu_row(name: str, us_per_call: float, derived: str):
+    """A row measured in a :func:`_cpu_child`."""
+    row(name, us_per_call, f"[cpu child process] {derived}")
+
+
+def _cpu_child(code: str):
+    """Run a bench body in a child process on the CPU backend.  The
+    children are multi-device CPU simulations (forced host devices) or
+    process-global flag flips; ``JAX_PLATFORMS=cpu`` keeps them off the
+    accelerator this process holds, and their rows say so."""
+    import subprocess
+    root = os.path.join(os.path.dirname(__file__), "..")
+    return subprocess.run(
+        [sys.executable, "-c", code], capture_output=True,
+        text=True, timeout=560,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": os.pathsep.join(
+                 [root, os.path.join(root, "src")])})
+
+
 # ----------------------------------------------------------------------
 # live elasticity (DESIGN.md section 12): runs in a subprocess with 16
 # forced host devices so the main bench process keeps the real device
@@ -599,42 +620,37 @@ print(f"REBALANCE,{us2:.2f},{counts[hot_owner]},{counts.sum()}")
 
 
 def bench_elasticity():
-    import subprocess
-    root = os.path.join(os.path.dirname(__file__), "..")
-    r = subprocess.run(
-        [sys.executable, "-c", _ELASTIC_CODE], capture_output=True,
-        text=True, timeout=560,
-        env={**os.environ, "PYTHONPATH": os.path.join(root, "src")})
+    r = _cpu_child(_ELASTIC_CODE)
     if r.returncode != 0:      # pragma: no cover - surfacing CI breakage
         raise RuntimeError(f"elasticity bench failed:\n{r.stderr}")
     for line in r.stdout.splitlines():
         if line.startswith("HOST,"):
             _, us, rows, moved = line.split(",")
-            row("elastic_scale_8to16_host", float(us),
-                f"physical grow 8->16 slots: drain + host remap "
-                f"{moved} of {rows} rows + recompile+step (the "
-                f"shape-change tier)")
+            _cpu_row("elastic_scale_8to16_host", float(us),
+                     f"physical grow 8->16 slots: drain + host remap "
+                     f"{moved} of {rows} rows + recompile+step (the "
+                     f"shape-change tier)")
         elif line.startswith("DEVICE,"):
             _, us, rows, moved, pause, nbytes = line.split(",")
-            row("elastic_scale_8to16", float(us),
-                f"device tier: activate 8->16 on a 16-slot mesh, "
-                f"all_to_all {moved} of {rows} rows "
-                f"({int(nbytes)} B), no recompile; loss-free")
+            _cpu_row("elastic_scale_8to16", float(us),
+                     f"device tier: activate 8->16 on a 16-slot mesh, "
+                     f"all_to_all {moved} of {rows} rows "
+                     f"({int(nbytes)} B), no recompile; loss-free")
             p = float(pause)
-            row("migration_rows_per_s", p * 1e6,
-                f"{int(moved)/p:.2e} rows/s through the device "
-                f"migration kernel (pause {p*1e3:.1f} ms)")
+            _cpu_row("migration_rows_per_s", p * 1e6,
+                     f"{int(moved)/p:.2e} rows/s through the device "
+                     f"migration kernel (pause {p*1e3:.1f} ms)")
         elif line.startswith("SHRINK,"):
             _, us, moved, pause = line.split(",")
-            row("elastic_shrink_16to8", float(us),
-                f"device tier: planned leave 16->8 active, all_to_all "
-                f"{moved} rows off the parked slots + step "
-                f"(pause {float(pause)*1e3:.1f} ms)")
+            _cpu_row("elastic_shrink_16to8", float(us),
+                     f"device tier: planned leave 16->8 active, all_to_all "
+                     f"{moved} rows off the parked slots + step "
+                     f"(pause {float(pause)*1e3:.1f} ms)")
         elif line.startswith("REBALANCE,"):
             _, us, vn, budget = line.split(",")
-            row("rebalance_hot_ring", float(us),
-                f"load-aware reweight: hot shard down to {vn}/{budget} "
-                f"vnodes, ring swap without recompilation")
+            _cpu_row("rebalance_hot_ring", float(us),
+                     f"load-aware reweight: hot shard down to {vn}/{budget} "
+                     f"vnodes, ring swap without recompilation")
 
 
 # ----------------------------------------------------------------------
@@ -813,20 +829,15 @@ print(f"CLOSEDLOOP,{us:.2f},{'|'.join(segs)}")
 
 
 def bench_closed_loop():
-    import subprocess
-    root = os.path.join(os.path.dirname(__file__), "..")
-    r = subprocess.run(
-        [sys.executable, "-c", _CLOSED_LOOP_CODE], capture_output=True,
-        text=True, timeout=560,
-        env={**os.environ, "PYTHONPATH": os.path.join(root, "src")})
+    r = _cpu_child(_CLOSED_LOOP_CODE)
     if r.returncode != 0:      # pragma: no cover - surfacing CI breakage
         raise RuntimeError(f"closed-loop bench failed:\n{r.stderr}")
     for line in r.stdout.splitlines():
         if line.startswith("CLOSEDLOOP,"):
             _, us, segs = line.split(",")
-            row("closed_loop_scale", float(us),
-                f"square-wave load, LoadAutoscaler 2->4->2: shard "
-                f"trace {segs} (us/tick incl. reconfigures)")
+            _cpu_row("closed_loop_scale", float(us),
+                     f"square-wave load, LoadAutoscaler 2->4->2: shard "
+                     f"trace {segs} (us/tick incl. reconfigures)")
 
 
 # ----------------------------------------------------------------------
@@ -1033,14 +1044,7 @@ def bench_ml_mapper_throughput_x64():
     cost of the wide-key mode on an f32 model path, answering the PR-9
     open item: compare against ``ml_mapper_throughput`` before
     defaulting any workload to 64-bit keys."""
-    import subprocess
-    root = os.path.join(os.path.dirname(__file__), "..")
-    r = subprocess.run(
-        [sys.executable, "-c", _X64_CODE], capture_output=True,
-        text=True, timeout=560,
-        env={**os.environ,
-             "PYTHONPATH": os.pathsep.join(
-                 [root, os.path.join(root, "src")])})
+    r = _cpu_child(_X64_CODE)
     if r.returncode != 0:      # pragma: no cover - surfacing CI breakage
         raise RuntimeError(f"x64 ml-mapper bench failed:\n{r.stderr}")
     base = next((u for n, u, _ in ROWS
@@ -1050,9 +1054,9 @@ def bench_ml_mapper_throughput_x64():
             _, us, B = line.split(",")
             us, B = float(us), int(B)
             vs = (f", {us / base:.2f}x the int32/f32 row" if base else "")
-            row("ml_mapper_throughput_x64", us,
-                f"{B/(us/1e6):.0f} events/s with jax_enable_x64 + "
-                f"int64 keys (same model, subprocess){vs}")
+            _cpu_row("ml_mapper_throughput_x64", us,
+                     f"{B/(us/1e6):.0f} events/s with jax_enable_x64 + "
+                     f"int64 keys (same model, subprocess){vs}")
 
 
 def bench_semantic_topk():
@@ -1173,6 +1177,7 @@ def bench_kernels():
 
 
 def main() -> None:
+    use_compile_cache()
     print("name,us_per_call,derived")
     bench_event_throughput()
     bench_sequential_throughput()

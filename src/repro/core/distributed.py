@@ -59,15 +59,6 @@ from repro.telemetry.metrics import MetricsRegistry, TelemetryConfig
 from repro.telemetry.trace import ControlLog, Tracer, null_span
 
 
-def _axis_size(axis_names) -> int:
-    """Static size of the (possibly multi-) mesh axis we're mapped over.
-    ``jax.lax.axis_size`` only exists on newer jax; ``psum(1, axes)``
-    constant-folds to a python int on every version we support."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_names))
-    return int(jax.lax.psum(1, axis_names))
-
-
 def _linear_shard_index(axis_names):
     """This shard's linearized id over the (possibly multi-) mesh axes —
     the shard-dim index of the global state arrays, matching
@@ -75,7 +66,7 @@ def _linear_shard_index(axis_names):
     idx = None
     for a in axis_names:
         i = jax.lax.axis_index(a)
-        idx = i if idx is None else idx * _axis_size(a) + i
+        idx = i if idx is None else idx * jax.lax.axis_size(a) + i
     return idx
 
 
@@ -94,7 +85,7 @@ def exchange(batch: EventBatch, dest, axis_names, cap_per_dest: int
     dropped and counted (bounded queues, paper section 4.3).  Returns the
     received local batch [n*cap] and the local overflow count.
     """
-    n = _axis_size(axis_names)
+    n = jax.lax.axis_size(axis_names)
     B = batch.capacity
     dest = jnp.where(batch.valid, dest, n)              # invalid -> sink
     order = jnp.argsort(dest, stable=True)
@@ -155,7 +146,7 @@ def exchange_rows(t: tbl.SlateTable, dest_salt: int, ring_hashes,
     sizes it from an exact on-device count (``_migrate_device``), so
     nothing is lost in practice.  Returns ``(new_table, moved_out)``.
     """
-    n = _axis_size(axis_names)
+    n = jax.lax.axis_size(axis_names)
     me = _linear_shard_index(axis_names)
     C = t.capacity
     valid = t.keys != tbl.EMPTY
@@ -277,7 +268,7 @@ def exchange_queue(q: q_mod.QueueState, dest_salt: int, ring_hashes,
     post-migration backlog, the rebalance window's load signal.
     Returns ``(new_queue, moved_out)``.
     """
-    n = _axis_size(axis_names)
+    n = jax.lax.axis_size(axis_names)
     me = _linear_shard_index(axis_names)
     buf = q.buf
     C = buf.capacity
@@ -402,7 +393,12 @@ class DistributedEngine:
     def __init__(self, workflow: Workflow, mesh: Mesh,
                  config: Optional[DistConfig] = None):
         self.wf = workflow
-        self.mesh = mesh
+        # the engine places state with NamedSharding and runs its own
+        # shard_map programs, so every axis is Auto (jax.make_mesh gives
+        # Explicit axes by default, which reject that placement)
+        self.mesh = Mesh(mesh.devices, mesh.axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(mesh.axis_names))
         self.cfg = config or DistConfig()
         self.key_dtype = resolve_key_dtype(self.cfg.key_dtype)
         self.axes = self.cfg.axis_names
@@ -478,42 +474,49 @@ class DistributedEngine:
 
     # ---- state ----
     def init_state(self):
+        """Fresh global state, built by one jitted program whose outputs
+        are laid out per ``_shard_tree``: each shard's rows are created
+        on its own device (building them eagerly would stage every
+        shard's tables on the first device)."""
         def per_shard(make):
-            one = make()
             return jax.tree.map(
                 lambda x: jnp.broadcast_to(
-                    x[None], (self.n_shards,) + x.shape).copy(), one)
+                    x[None], (self.n_shards,) + x.shape), make())
 
         kd = self.key_dtype
-        queues = {op.name: per_shard(partial(
-            q_mod.make_queue, self.cfg.queue_capacity, op.in_value_spec,
-            key_dtype=kd))
-            for op in self.wf.operators}
-        tables = {up.name: per_shard(partial(
-            tbl.make_table, up.table_capacity, up.slate_spec(),
-            key_dtype=kd))
-            for up in self.wf.updaters()}
-        z = lambda: jnp.zeros((self.n_shards,), jnp.int32)
-        state = {
-            "queues": queues, "tables": tables,
-            "tick": z(),
-            "exchange_dropped": z(),
-            "throttle_hits": z(),
-            "deferred": z(),
-            "processed": {op.name: z() for op in self.wf.operators},
-        }
-        if self.tele_cfg is not None:
-            tc = self.tele_cfg
-            state["sketch"] = per_shard(partial(
-                sk_mod.make_sketch, tc.depth, tc.width, tc.sample,
+
+        def build():
+            queues = {op.name: per_shard(partial(
+                q_mod.make_queue, self.cfg.queue_capacity,
+                op.in_value_spec, key_dtype=kd))
+                for op in self.wf.operators}
+            tables = {up.name: per_shard(partial(
+                tbl.make_table, up.table_capacity, up.slate_spec(),
                 key_dtype=kd))
-            if tc.latency_buckets > 0:
-                state["lat_hist"] = per_shard(partial(
-                    lat_mod.make_hist,
-                    [u.name for u in self.wf.updaters()],
-                    tc.latency_buckets))
-        state = jax.tree.map(lambda x: jnp.array(x, copy=True), state)
-        return jax.device_put(state, self._shard_tree(state))
+                for up in self.wf.updaters()}
+            z = lambda: jnp.zeros((self.n_shards,), jnp.int32)
+            state = {
+                "queues": queues, "tables": tables,
+                "tick": z(),
+                "exchange_dropped": z(),
+                "throttle_hits": z(),
+                "deferred": z(),
+                "processed": {op.name: z() for op in self.wf.operators},
+            }
+            if self.tele_cfg is not None:
+                tc = self.tele_cfg
+                state["sketch"] = per_shard(partial(
+                    sk_mod.make_sketch, tc.depth, tc.width, tc.sample,
+                    key_dtype=kd))
+                if tc.latency_buckets > 0:
+                    state["lat_hist"] = per_shard(partial(
+                        lat_mod.make_hist,
+                        [u.name for u in self.wf.updaters()],
+                        tc.latency_buckets))
+            return state
+
+        shardings = self._shard_tree(jax.eval_shape(build))
+        return jax.jit(build, out_shardings=shardings)()
 
     def _shard_tree(self, state):
         def spec(path_unused, leaf):
@@ -702,18 +705,16 @@ class DistributedEngine:
     def step(self, state, sources: Dict[str, EventBatch]):
         """sources: global batches with leading dim n_shards*B_loc or
         [n_shards, B_loc] — pass [n_shards, B_loc] (leading shard axis)."""
-        from jax.experimental.shard_map import shard_map
         if self._step is None:
             sharded, rep = P(self.axes), P()
             state_specs = self._spec_like(state)
             src_specs = jax.tree.map(lambda _: sharded, sources)
 
             def run(st, src, rh, rs, hk, hv):
-                fn = shard_map(self._local_tick, mesh=self.mesh,
-                               in_specs=(state_specs, src_specs, rep, rep,
-                                         rep, rep),
-                               out_specs=sharded,
-                               check_rep=False)
+                fn = jax.shard_map(
+                    self._local_tick, mesh=self.mesh,
+                    in_specs=(state_specs, src_specs, rep, rep, rep, rep),
+                    out_specs=sharded, check_vma=False)
                 return fn(st, src, rh, rs, hk, hv)
 
             self._step = jax.jit(run, donate_argnums=(0,))
@@ -731,7 +732,6 @@ class DistributedEngine:
         [T, n_shards] on-device per-tick trace, so the host syncs once
         per chunk for the backpressure signal.
         """
-        from jax.experimental.shard_map import shard_map
         if self._chunk is None:
             stacked = P(None, self.axes)
             rep = P()
@@ -746,11 +746,10 @@ class DistributedEngine:
                 return final, outs, hits
 
             def run(st, src, rh, rs, hk, hv):
-                fn = shard_map(local_chunk, mesh=self.mesh,
-                               in_specs=(state_specs, src_specs, rep, rep,
-                                         rep, rep),
-                               out_specs=(state_specs, stacked, stacked),
-                               check_rep=False)
+                fn = jax.shard_map(
+                    local_chunk, mesh=self.mesh,
+                    in_specs=(state_specs, src_specs, rep, rep, rep, rep),
+                    out_specs=(state_specs, stacked, stacked), check_vma=False)
                 return fn(st, src, rh, rs, hk, hv)
 
             self._chunk = jax.jit(run, donate_argnums=(0,))
@@ -804,18 +803,17 @@ class DistributedEngine:
 
     def _step_empty(self, state):
         """One source-less tick (drain barriers, replay gap ticks)."""
-        from jax.experimental.shard_map import shard_map
         if self._empty_step is None:
             sharded, rep = P(self.axes), P()
             state_specs = self._spec_like(state)
 
             def run(st, rh, rs, hk, hv):
-                fn = shard_map(
+                fn = jax.shard_map(
                     lambda s, h, r, k, v: self._local_tick(s, {}, h, r,
                                                            k, v),
                     mesh=self.mesh,
                     in_specs=(state_specs, rep, rep, rep, rep),
-                    out_specs=sharded, check_rep=False)
+                    out_specs=sharded, check_vma=False)
                 return fn(st, rh, rs, hk, hv)
 
             self._empty_step = jax.jit(run, donate_argnums=(0,))
@@ -1630,7 +1628,6 @@ class DistributedEngine:
         ``exchange_queue`` for every backlogged operator queue in one
         shard_map dispatch.  Slates and events never leave the device.
         Returns ``(state, moved_rows, moved_events, bytes_moved)``."""
-        from jax.experimental.shard_map import shard_map
         updaters = list(self.wf.updaters())
         rh, rs = self.ring.table()
         tables, queues = state["tables"], state["queues"]
@@ -1668,10 +1665,10 @@ class DistributedEngine:
                 return {"rows": rows, "events": evs}
 
             def plan(tb, qs, rh_, rs_):
-                return shard_map(plan_local, mesh=self.mesh,
-                                 in_specs=specs + (rep, rep),
-                                 out_specs=sharded,
-                                 check_rep=False)(tb, qs, rh_, rs_)
+                return jax.shard_map(plan_local, mesh=self.mesh,
+                                     in_specs=specs + (rep, rep),
+                                     out_specs=sharded,
+                                     check_vma=False)(tb, qs, rh_, rs_)
             self._plan_fn = jax.jit(plan)
         plan = jax.device_get(self._plan_fn(tables, queues, rh, rs))
         moved = {name: int(np.asarray(c).sum())
@@ -1716,7 +1713,6 @@ class DistributedEngine:
 
     def _make_migrate_fn(self, tables, updaters, cap_rows: int,
                          cap_ev: int):
-        from jax.experimental.shard_map import shard_map
         sharded, rep = P(self.axes), P()
         specs = self._spec_like(tables)
         operators = list(self.wf.operators)
@@ -1746,10 +1742,10 @@ class DistributedEngine:
 
         def run(tb, qs, rh_, rs_):
             qspecs = self._spec_like(qs)
-            return shard_map(mig_local, mesh=self.mesh,
-                             in_specs=(specs, qspecs, rep, rep),
-                             out_specs=(sharded, sharded),
-                             check_rep=False)(tb, qs, rh_, rs_)
+            return jax.shard_map(mig_local, mesh=self.mesh,
+                                 in_specs=(specs, qspecs, rep, rep),
+                                 out_specs=(sharded, sharded),
+                                 check_vma=False)(tb, qs, rh_, rs_)
         return jax.jit(run, donate_argnums=(0, 1))
 
     def compact(self, state, *, drain_max: int = 64):
@@ -2181,7 +2177,6 @@ class DistributedEngine:
         sum-of-masked equals select-of-owner (asserted in tests against
         the per-key ``read_slate`` loop).  Returns replicated
         ``(role_mask [n_shards, Q], rows [n_shards, Q, ...])``."""
-        from jax.experimental.shard_map import shard_map
         from repro.kernels.slate_lookup import ops as lk_ops
         rep = P()
         tspec = self._spec_like(tables)
@@ -2211,9 +2206,9 @@ class DistributedEngine:
             return gath(mask), jax.tree.map(gath, rows)
 
         def run(tb, karr, rh_, rs_, hk_, hv_):
-            fn = shard_map(local, mesh=self.mesh,
-                           in_specs=(tspec, rep, rep, rep, rep, rep),
-                           out_specs=(rep, rep), check_rep=False)
+            fn = jax.shard_map(local, mesh=self.mesh,
+                               in_specs=(tspec, rep, rep, rep, rep, rep),
+                               out_specs=(rep, rep), check_vma=False)
             return fn(tb, karr, rh_, rs_, hk_, hv_)
 
         return jax.jit(run)

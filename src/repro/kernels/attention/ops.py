@@ -2,7 +2,9 @@
 
 The dry-run / CPU tests always take the ref path (Pallas does not target
 CPU); on a real TPU backend ``impl="auto"`` resolves to the Pallas kernel
-when the shape is supported (head_dim multiple of 128 tiling etc.).
+when the shape is supported (head_dim multiple of 128 tiling etc.) and to
+the ref otherwise.  An explicit ``impl="pallas"`` raises on an
+unsupported shape.
 """
 from __future__ import annotations
 
@@ -11,22 +13,18 @@ import jax
 from repro.kernels.attention import ref as _ref
 
 
-def _tpu_available() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
 def mha(q, k, v, *, causal: bool = True, window: int = 0, q_offset: int = 0,
         chunk: int = 512, impl: str = "auto"):
-    if impl == "auto":
-        impl = "pallas" if _tpu_available() else "ref"
-    if impl == "pallas":
+    if impl == "auto" and jax.default_backend() != "tpu":
+        impl = "ref"
+    if impl in ("auto", "pallas"):
         from repro.kernels.flash_attention import kernel as _k
         if _k.supported(q, k, v, causal=causal, window=window):
             return _k.flash_attention(q, k, v, causal=causal, window=window,
                                       q_offset=q_offset)
-        impl = "ref"
+        if impl == "pallas":
+            raise ValueError(
+                f"mha impl='pallas': flash_attention does not support "
+                f"q {q.shape}, v {v.shape}")
     return _ref.mha(q, k, v, causal=causal, window=window,
                     q_offset=q_offset, chunk=chunk)

@@ -2,8 +2,9 @@
 
 ``impl`` (the same backend vocabulary as ``kernels/slate_update``):
   - "auto":      Pallas on TPU, jnp oracle elsewhere
-  - "pallas":    force the kernel (falls back to ref if unsupported)
-  - "interpret": Pallas body in interpreter mode (CPU-testable)
+  - "pallas":    the kernel (raises if the shape is unsupported)
+  - "interpret": Pallas body in interpreter mode (CPU-testable; raises
+                 like "pallas")
   - "jnp" / "ref": pure-jnp scatter-add oracle
 
 All backends are exact integer adds, so they agree bitwise.  ``add``
@@ -22,10 +23,13 @@ def countmin_update(counts, cols, add, *, impl: str = "auto"):
         impl = "pallas" if jax.default_backend() == "tpu" else "ref"
     if impl in ("pallas", "interpret"):
         from repro.kernels.countmin import kernel as _k
-        if _k.supported(counts, cols):
-            return _k.countmin_update(counts, cols, add,
-                                      interpret=(impl == "interpret"))
-        impl = "ref"
+        if not _k.supported(counts, cols):
+            raise ValueError(
+                f"countmin impl={impl!r}: the kernel needs [rows, width] "
+                f"counts with width % 128 == 0 and [rows, B] columns, "
+                f"got {counts.shape} and {cols.shape}")
+        return _k.countmin_update(counts, cols, add,
+                                  interpret=(impl == "interpret"))
     if impl not in ("ref", "jnp"):
         raise ValueError(f"unknown countmin impl {impl!r}")
     return _ref.countmin_update(counts, cols, add)

@@ -1,13 +1,14 @@
 """Latency-histogram update — the observability hot-path kernel.
 
 One invocation folds a microbatch of power-of-two latency buckets into
-the [rows, width] histogram held in VMEM: each row's bucket indices
-are expanded to a [B, width] one-hot mask and reduced over B — the
-same VPU-friendly shape as the count-min kernel (no scalar scatter in
-the inner loop).  The histogram is aliased in/out so the update is
-in-place; *bucketizing* (the clz-based power-of-two binning) stays
-outside the kernel, plain jnp on the already-resident latencies,
-mirroring how ``countmin_update`` receives pre-hashed columns.
+the [rows, width] histogram: each row's bucket indices are expanded to
+a one-hot mask and reduced over the batch — exactly the count-min
+kernel's body (``kernels/countmin/kernel.onehot_update``), so the two
+share it and differ only in the kernel's name.  The histogram is
+aliased in/out so the update is in-place; *bucketizing* (the clz-based
+power-of-two binning) stays outside the kernel, plain jnp on the
+already-resident latencies, mirroring how ``countmin_update`` receives
+pre-hashed columns.
 
 Masked-out events are folded into a sink column (``width``, which no
 iota lane matches) before the call, so the kernel carries no validity
@@ -18,49 +19,14 @@ bucket index saturates below it.
 """
 from __future__ import annotations
 
-import functools
+from repro.kernels.countmin.kernel import onehot_update, supported
 
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
+__all__ = ["histogram_update", "supported"]
 
 
-def _hist_kernel(cols_ref, counts_in_ref, counts_ref, *,
-                 rows: int, B: int, width: int):
-    for r in range(rows):                       # static, tiny
-        cols = cols_ref[:, r:r + 1]             # [B, 1]
-        iota = jax.lax.broadcasted_iota(jnp.int32, (B, width), 1)
-        hit = (iota == cols).astype(jnp.int32)  # sink column never hits
-        counts_ref[r:r + 1, :] = counts_ref[r:r + 1, :] + \
-            jnp.sum(hit, axis=0, keepdims=True)
-
-
-def supported(counts, cols) -> bool:
-    return (counts.ndim == 2 and cols.ndim == 2
-            and counts.shape[1] % 128 == 0
-            and cols.shape[0] == counts.shape[0])
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def histogram_update(counts, cols, add, *, interpret: bool = False):
     """counts: [rows, width] int32 (aliased in/out); cols: [rows, B]
     int32 bucket indices; add: [B] int32 0/1 increment per event.
     Returns the updated histogram."""
-    rows, width = counts.shape
-    B = cols.shape[1]
-    # fold the increment mask into a sink column and transpose to
-    # [B, rows] so the kernel stays rank-2 throughout
-    cols_t = jnp.where(add[None, :] > 0, cols,
-                       jnp.int32(width)).T.astype(jnp.int32)
-    kernel = functools.partial(_hist_kernel, rows=rows, B=B, width=width)
-    return pl.pallas_call(
-        kernel,
-        in_specs=[
-            pl.BlockSpec((B, rows), lambda: (0, 0)),      # cols (T)
-            pl.BlockSpec((rows, width), lambda: (0, 0)),  # hist alias
-        ],
-        out_specs=pl.BlockSpec((rows, width), lambda: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct(counts.shape, counts.dtype),
-        input_output_aliases={1: 0},
-        interpret=interpret,
-    )(cols_t, counts)
+    return onehot_update(counts, cols, add, interpret=interpret,
+                         name="histogram_update")

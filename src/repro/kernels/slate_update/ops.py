@@ -2,8 +2,9 @@
 
 ``impl``:
   - "auto":      Pallas on TPU, jnp oracle elsewhere
-  - "pallas":    force the kernel (falls back to ref if unsupported)
-  - "interpret": Pallas body in interpreter mode (CPU-testable)
+  - "pallas":    the kernel (raises if the shape is unsupported)
+  - "interpret": Pallas body in interpreter mode (CPU-testable; raises
+                 like "pallas")
   - "ref":       pure-jnp segment-sum oracle
 """
 from __future__ import annotations
@@ -31,13 +32,15 @@ def slate_update(keys_sorted, deltas, slots, table_vals, *,
         impl = "pallas" if jax.default_backend() == "tpu" else "ref"
     if impl in ("pallas", "interpret"):
         from repro.kernels.slate_update import kernel as _k
-        if _k.supported(deltas):
-            ks = keys_sorted
-            if jnp.dtype(ks.dtype).itemsize > 4:
-                ks = _segment_ids(ks)
-            return _k.slate_update(ks, deltas, slots, table_vals,
-                                   interpret=(impl == "interpret"), op=op)
-        impl = "ref"
+        if not _k.supported(deltas):
+            raise ValueError(
+                f"slate_update impl={impl!r}: the kernel needs [B, D] "
+                f"deltas with D % 8 == 0, got {deltas.shape}")
+        ks = keys_sorted
+        if jnp.dtype(ks.dtype).itemsize > 4:
+            ks = _segment_ids(ks)
+        return _k.slate_update(ks, deltas, slots, table_vals,
+                               interpret=(impl == "interpret"), op=op)
     if impl != "ref":
         raise ValueError(f"unknown slate_update impl {impl!r}")
     return _ref.slate_update(keys_sorted, deltas, slots, table_vals, op=op)

@@ -12,18 +12,11 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n):
-    """``axis_types`` only exists on newer jax; older versions treat all
-    axes as auto already, so just omit it there."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
-    return {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_data: int = None, n_model: int = 1,
@@ -32,4 +25,4 @@ def make_host_mesh(n_data: int = None, n_model: int = 1,
     n = len(jax.devices())
     n_data = n_data or (n // n_model)
     return jax.make_mesh((n_data, n_model), axes,
-                         **_mesh_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

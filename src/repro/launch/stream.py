@@ -51,6 +51,7 @@ import numpy as np
 
 from repro import (App, AutoscalePolicy, EventBatch, LoadAutoscaler,
                    RuntimeConfig)
+from repro.launch.compile_cache import use_compile_cache
 
 
 def make_app(args) -> App:
@@ -151,6 +152,7 @@ def parse_autoscale(spec: str):
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--dir", required=True,
                     help="durability root (wal.log, store/, FRONTIER)")

@@ -133,7 +133,6 @@ def _round_up_i(x: int, mult: int) -> int:
 
 
 def apply_sharded(p, x, ctx: Ctx, *, cfg: ModelConfig):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -237,12 +236,12 @@ def apply_sharded(p, x, ctx: Ctx, *, cfg: ModelConfig):
         y = jax.ops.segment_sum(contrib, st, num_segments=T_loc)
         return y.reshape(B_loc, S_loc, D).astype(xl.dtype), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(x_spec, P(None, None), P(tp, fsdp or None, None),
                   P(tp, fsdp or None, None), P(tp, None, fsdp or None)),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_in"], p["w_out"])
 
     if "shared" in p:

@@ -100,9 +100,7 @@ def begin_dirty_snapshot(table: tbl.SlateTable):
     token = (jnp.copy(table.dirty), jnp.copy(table.keys),
              jnp.copy(table.ts), jax.tree.map(jnp.copy, table.vals))
     for leaf in jax.tree.leaves(token):
-        copy_async = getattr(leaf, "copy_to_host_async", None)
-        if copy_async is not None:
-            copy_async()
+        leaf.copy_to_host_async()
     cleared = tbl.SlateTable(
         keys=table.keys, ts=table.ts,
         dirty=jnp.zeros_like(table.dirty),

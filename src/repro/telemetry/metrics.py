@@ -252,9 +252,7 @@ class MetricsRegistry:
         # chunk dispatch; the async copy then drains in the background
         tree = jax.tree.map(jnp.copy, tree)
         for leaf in jax.tree.leaves(tree):
-            copy_async = getattr(leaf, "copy_to_host_async", None)
-            if copy_async is not None:
-                copy_async()
+            leaf.copy_to_host_async()
         return (engine, tree)
 
     def finish_observe(self, pending) -> TelemetryReport:

@@ -45,7 +45,6 @@ def test_error_feedback_unbiased_over_steps():
 
 def test_compressed_psum_tree_single_device():
     """shard_map over a 1-device mesh: compressed psum == identity-ish."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_host_mesh
     mesh = make_host_mesh(n_data=1, n_model=1)
@@ -55,9 +54,9 @@ def test_compressed_psum_tree_single_device():
     def f(gs, es):
         return coll.compressed_psum_tree(gs, es, "data")
 
-    out, err = jax.jit(shard_map(
+    out, err = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-        check_rep=False))(g, e)
+        check_vma=False))(g, e)
     assert np.allclose(np.asarray(out["w"] + err["w"]),
                        np.asarray(g["w"]), atol=1e-6)
 

@@ -1,7 +1,7 @@
 """Parity tests for the fused slate-update path (ISSUE 1 tentpole):
 Pallas kernel (interpret) vs jnp oracle vs the generic apply path, on
 Zipf-skewed and all-duplicate-key batches, plus the ``supported()``
-fallback and an engine-level fused run."""
+guard and an engine-level fused run."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -89,9 +89,32 @@ def test_kernel_interpret_matches_ref_oracle():
     assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-4
 
 
+@pytest.mark.parametrize("op", ["sum", "max"])
+def test_kernel_multi_tile_runs_exact(op):
+    """A batch of several kernel tiles (padded last tile) whose longest
+    run crosses two tile edges, into a table whose capacity is not a
+    whole number of 128-lane windows: integer-valued deltas make every
+    combine order exact, so the kernel must equal the oracle bitwise."""
+    from repro.kernels.slate_update import kernel, ops
+    rng = np.random.default_rng(8)
+    B, D, C = 2 * kernel.TILE_B + 452, 8, 1000
+    keys = np.sort(np.concatenate([
+        np.full(1500, 7), rng.integers(0, 300, B - 1500)])).astype(np.int32)
+    deltas = rng.integers(0, 5, size=(B, D)).astype(np.float32)
+    run_last = np.concatenate([keys[1:] != keys[:-1], [True]])
+    slots = np.where(run_last, (keys * 7 + 3) % C, -1).astype(np.int32)
+    table = rng.integers(0, 9, size=(C, D)).astype(np.float32)
+    args = (jnp.asarray(keys), jnp.asarray(deltas), jnp.asarray(slots),
+            jnp.asarray(table))
+    a = ops.slate_update(*args, impl="interpret", op=op)
+    b = ops.slate_update(*args, impl="ref", op=op)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_unsupported_width_falls_back_to_ref():
-    """D not lane-aligned -> supported() is False and the dispatcher
-    silently takes the oracle, even when Pallas is requested."""
+    """D not lane-aligned -> supported() is False, and a dispatcher asked
+    for the kernel raises instead of quietly serving the oracle; the
+    oracle itself still takes the shape."""
     from repro.kernels.slate_update import kernel, ops
     rng = np.random.default_rng(4)
     B, D, C = 32, 5, 64                       # 5 % 8 != 0
@@ -101,13 +124,13 @@ def test_unsupported_width_falls_back_to_ref():
     slots = np.where(run_last, keys % C, -1).astype(np.int32)
     table = np.zeros((C, D), np.float32)
     assert not kernel.supported(jnp.asarray(deltas))
-    out = ops.slate_update(jnp.asarray(keys), jnp.asarray(deltas),
-                           jnp.asarray(slots), jnp.asarray(table),
-                           impl="pallas")
-    ref = ops.slate_update(jnp.asarray(keys), jnp.asarray(deltas),
-                           jnp.asarray(slots), jnp.asarray(table),
-                           impl="ref")
-    assert np.allclose(np.asarray(out), np.asarray(ref))
+    args = (jnp.asarray(keys), jnp.asarray(deltas), jnp.asarray(slots),
+            jnp.asarray(table))
+    for impl in ("pallas", "interpret"):
+        with pytest.raises(ValueError, match="D % 8"):
+            ops.slate_update(*args, impl=impl)
+    ref = ops.slate_update(*args, impl="ref")
+    assert ref.shape == (C, D)
 
 
 def test_pack_unpack_roundtrip():

@@ -70,6 +70,24 @@ def test_lookup_kernel_interpret_matches_jnp_oracle():
         np.asarray(t.keys)[np.asarray(slot_k)[f]], q[f])
 
 
+def test_lookup_kernel_multi_tile_exact():
+    """More queries than one kernel tile (padded last tile), against a
+    table whose capacity is not a whole number of 128-lane windows."""
+    from repro.kernels.slate_lookup import kernel as lk_kernel
+    from repro.kernels.slate_lookup import ops as lk_ops
+    t, keys = _filled_table(n_rows=300, cap=1000)
+    rng = np.random.default_rng(3)
+    n_hit = 2 * lk_kernel.TILE_Q
+    q = jnp.asarray(np.concatenate([
+        rng.choice(keys, n_hit),
+        rng.integers(300_000, 400_000, 500)]).astype(np.int32))
+    got = lk_ops.slate_lookup(t.keys, q, t.vals["v"], impl="interpret")
+    want = lk_ops.slate_lookup(t.keys, q, t.vals["v"], impl="jnp")
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert bool(np.asarray(got[1])[:n_hit].all())
+
+
 def test_lookup_tree_multi_leaf_falls_back_bitwise():
     """Slate specs with several / scalar leaves can't use the kernel;
     lookup_tree must serve them through the jnp gather, same answers."""
