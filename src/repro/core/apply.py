@@ -16,6 +16,13 @@
   events beyond the bound are *deferred* back to the caller (re-queued
   next tick), which is how a hotspot manifests here — and what the
   two-choice + key-splitting mitigations relieve.
+
+Every path names its phases with ``jax.named_scope`` (DESIGN.md 18.2),
+which changes only the operations' metadata: ``apply.sort`` (sort,
+run boundaries, lift, pre-combine), ``apply.probe`` (``insert_or_find``),
+``apply.pack`` (the packed path's fresh-slot zeroing, pack and unpack)
+and ``apply.write`` (slate read/merge/write, the ts/dirty scatter).  The
+``slate_update`` kernel call sits in none of them.
 """
 from __future__ import annotations
 
@@ -107,25 +114,28 @@ def apply_associative(updater: AssociativeUpdater, table: tbl.SlateTable,
         if impl != "auto" or jax.default_backend() == "tpu":
             return _apply_associative_fused(updater, table, batch, tick,
                                             impl=impl)
-    batch = batch.sort_by_key_ts()
-    B = batch.capacity
-    key = batch.key
-    prev_key = jnp.concatenate([jnp.full((1,), -2, key.dtype), key[:-1]])
-    boundary = key != prev_key                       # run starts
-    run_last = _last_valid_of_run(key, batch.valid)  # run totals live here
+    with jax.named_scope("apply.sort"):
+        batch = batch.sort_by_key_ts()
+        key = batch.key
+        prev_key = jnp.concatenate([jnp.full((1,), -2, key.dtype),
+                                    key[:-1]])
+        boundary = key != prev_key                       # run starts
+        run_last = _last_valid_of_run(key, batch.valid)  # run totals here
 
-    deltas = updater.lift(batch)
-    scanned = _segmented_combine(updater, deltas, boundary)
+        deltas = updater.lift(batch)
+        scanned = _segmented_combine(updater, deltas, boundary)
+        unique = run_last & batch.valid
 
-    unique = run_last & batch.valid
-    table, slot, found, placed = tbl.insert_or_find(table, key, unique)
-    ok = unique & placed
-    old = tbl.read_slates(table, slot, found & ok, updater.init_slate)
-    new = updater.merge(old, scanned)
-    table = tbl.write_slates(table, slot, ok, new, tick)
+    with jax.named_scope("apply.probe"):
+        table, slot, found, placed = tbl.insert_or_find(table, key, unique)
+    with jax.named_scope("apply.write"):
+        ok = unique & placed
+        old = tbl.read_slates(table, slot, found & ok, updater.init_slate)
+        new = updater.merge(old, scanned)
+        table = tbl.write_slates(table, slot, ok, new, tick)
 
-    emissions = updater.emit(key, old, new, batch.ts)
-    emissions = {s: eb.mask(ok) for s, eb in emissions.items()}
+        emissions = updater.emit(key, old, new, batch.ts)
+        emissions = {s: eb.mask(ok) for s, eb in emissions.items()}
     return table, emissions, batch.count()
 
 
@@ -143,37 +153,41 @@ def _apply_associative_fused(updater: AssociativeUpdater,
     sum monoid, which the generic "sum" leaf already uses; max is
     order-independent and therefore bitwise-identical)."""
     op = merge_monoid(updater)
-    batch = batch.sort_by_key_ts()
-    key = batch.key                       # invalid rows sorted to sink
-    run_last = _last_valid_of_run(key, batch.valid)
-    unique = run_last & batch.valid
-
     spec = packing.pack_spec(updater.slate_spec())
-    deltas = updater.lift(batch)
-    # segment totals combine whole runs; invalid rows sharing the sink
-    # run with a genuine key 2**31-1 must contribute the identity — zero
-    # for sum, and zero again for max thanks to the non-negative contract
-    deltas = jax.tree.map(
-        lambda d: jnp.where(_bshape(batch.valid, d), d,
-                            jnp.zeros_like(d)), deltas)
+    with jax.named_scope("apply.sort"):
+        batch = batch.sort_by_key_ts()
+        key = batch.key                   # invalid rows sorted to sink
+        run_last = _last_valid_of_run(key, batch.valid)
+        unique = run_last & batch.valid
+        deltas = updater.lift(batch)
+        # segment totals combine whole runs; invalid rows sharing the
+        # sink run with a genuine key 2**31-1 must contribute the
+        # identity — zero for sum, and zero again for max thanks to the
+        # non-negative contract
+        deltas = jax.tree.map(
+            lambda d: jnp.where(_bshape(batch.valid, d), d,
+                                jnp.zeros_like(d)), deltas)
     if (jax.tree.structure(deltas)
             != jax.tree.structure(updater.slate_spec(),
                                   is_leaf=_is_spec_leaf)):
         raise TypeError(
             f"sum_mergeable updater {updater.name!r}: lift() pytree must "
             "match slate_spec() structure for the packed path")
-    table, slot, found, placed = tbl.insert_or_find(table, key, unique)
-    ok = unique & placed
-    slots = jnp.where(ok, slot, jnp.int32(-1))            # -1 = no write
-    safe = jnp.where(ok, slot, table.capacity)
+    with jax.named_scope("apply.probe"):
+        table, slot, found, placed = tbl.insert_or_find(table, key, unique)
+        ok = unique & placed
+        slots = jnp.where(ok, slot, jnp.int32(-1))        # -1 = no write
+        safe = jnp.where(ok, slot, table.capacity)
 
-    # Newly placed keys may land in a slot freed by expire_ttl /
-    # fail_shard, which clear the key but keep the dead occupant's vals;
-    # the generic path masks them out via read_slates' init_slate
-    # substitution, the additive path must zero them before the add.
-    safe_fresh = jnp.where(ok & ~found, slot, table.capacity)
-    base_vals = jax.tree.map(
-        lambda tv: tv.at[safe_fresh].set(0, mode="drop"), table.vals)
+    with jax.named_scope("apply.pack"):
+        # Newly placed keys may land in a slot freed by expire_ttl /
+        # fail_shard, which clear the key but keep the dead occupant's
+        # vals; the generic path masks them out via read_slates'
+        # init_slate substitution, the additive path must zero them
+        # before the add.
+        safe_fresh = jnp.where(ok & ~found, slot, table.capacity)
+        base_vals = jax.tree.map(
+            lambda tv: tv.at[safe_fresh].set(0, mode="drop"), table.vals)
 
     backend = impl
     if backend == "auto":
@@ -184,30 +198,37 @@ def _apply_associative_fused(updater: AssociativeUpdater,
         # the slate leaves directly — no [C, D] table pack and no lane
         # padding on this side, so the CPU/GPU fallback touches only B
         # rows at the exact slate width.
-        packed_deltas = packing.pack(deltas, spec, pad=False)
-        totals = slate_ref.run_totals(key, packed_deltas, op=op)  # [B, D]
-        total_tree = packing.unpack(totals, spec)          # [B, ...]
-        if op == "max":
-            vals = jax.tree.map(
-                lambda tv, dv: tv.at[safe].max(dv.astype(tv.dtype),
-                                               mode="drop"),
-                base_vals, total_tree)
-        else:
-            vals = jax.tree.map(
-                lambda tv, dv: tv.at[safe].add(dv.astype(tv.dtype),
-                                               mode="drop"),
-                base_vals, total_tree)
+        with jax.named_scope("apply.pack"):
+            packed_deltas = packing.pack(deltas, spec, pad=False)
+        with jax.named_scope("apply.sort"):
+            totals = slate_ref.run_totals(key, packed_deltas, op=op)
+        with jax.named_scope("apply.pack"):
+            total_tree = packing.unpack(totals, spec)      # [B, ...]
+        with jax.named_scope("apply.write"):
+            if op == "max":
+                vals = jax.tree.map(
+                    lambda tv, dv: tv.at[safe].max(dv.astype(tv.dtype),
+                                                   mode="drop"),
+                    base_vals, total_tree)
+            else:
+                vals = jax.tree.map(
+                    lambda tv, dv: tv.at[safe].add(dv.astype(tv.dtype),
+                                                   mode="drop"),
+                    base_vals, total_tree)
     else:
-        packed_deltas = packing.pack(deltas, spec)        # [B, D] aligned
-        packed_vals = packing.pack(base_vals, spec)       # [C, D]
+        with jax.named_scope("apply.pack"):
+            packed_deltas = packing.pack(deltas, spec)    # [B, D] aligned
+            packed_vals = packing.pack(base_vals, spec)   # [C, D]
         packed_vals = slate_ops.slate_update(key, packed_deltas, slots,
                                              packed_vals, impl=backend,
                                              op=op)
-        vals = packing.unpack(packed_vals, spec)
+        with jax.named_scope("apply.pack"):
+            vals = packing.unpack(packed_vals, spec)
 
-    # bookkeeping scatter (ts / dirty), same slots write_slates would hit
-    ts = table.ts.at[safe].set(tick, mode="drop")
-    dirty = table.dirty.at[safe].set(True, mode="drop")
+    with jax.named_scope("apply.write"):
+        # bookkeeping scatter (ts / dirty), same slots write_slates hits
+        ts = table.ts.at[safe].set(tick, mode="drop")
+        dirty = table.dirty.at[safe].set(True, mode="drop")
     table = tbl.SlateTable(keys=table.keys, ts=ts, dirty=dirty, vals=vals,
                            dropped=table.dropped)
     return table, {}, batch.count()
@@ -222,17 +243,33 @@ def apply_sequential(updater: SequentialUpdater, table: tbl.SlateTable,
     Deferred = valid events whose per-key run exceeded ``max_run`` this
     tick (hotspot backpressure); the engine re-queues them.
     """
-    batch = batch.sort_by_key_ts()
+    with jax.named_scope("apply.sort"):
+        batch = batch.sort_by_key_ts()
+        B = batch.capacity
+        key, valid = batch.key, batch.valid
+        first_idx = jnp.searchsorted(key, key,
+                                     side="left").astype(jnp.int32)
+        pos = jnp.arange(B, dtype=jnp.int32) - first_idx
+        run_start = (pos == 0) & valid
+        in_budget = pos < updater.max_run
+        deferred = batch.mask(valid & ~in_budget)
+
+    with jax.named_scope("apply.probe"):
+        table, slot, found, placed = tbl.insert_or_find(table, key,
+                                                        run_start)
+    with jax.named_scope("apply.write"):
+        table, emissions = _step_runs(updater, table, batch,
+                                      run_start & placed, slot, found, tick)
+    n_proc = jnp.sum(valid & in_budget, dtype=jnp.int32)
+    return table, emissions, deferred, n_proc
+
+
+def _step_runs(updater: SequentialUpdater, table: tbl.SlateTable,
+               batch: EventBatch, ok, slot, found, tick):
+    """The sequential path's slate read, per-run step scan and write:
+    ``(table, emissions)``."""
     B = batch.capacity
     key, valid = batch.key, batch.valid
-    first_idx = jnp.searchsorted(key, key, side="left").astype(jnp.int32)
-    pos = jnp.arange(B, dtype=jnp.int32) - first_idx
-    run_start = (pos == 0) & valid
-    in_budget = pos < updater.max_run
-    deferred = batch.mask(valid & ~in_budget)
-
-    table, slot, found, placed = tbl.insert_or_find(table, key, run_start)
-    ok = run_start & placed
     slates = tbl.read_slates(table, slot, found & ok, updater.init_slate)
 
     # emission accumulators at sorted-row granularity
@@ -291,8 +328,7 @@ def apply_sequential(updater: SequentialUpdater, table: tbl.SlateTable,
             value=em_vals[s],
             valid=em_flag[s],
         )
-    n_proc = jnp.sum(valid & in_budget, dtype=jnp.int32)
-    return table, emissions, deferred, n_proc
+    return table, emissions
 
 
 def _is_spec_leaf(x):

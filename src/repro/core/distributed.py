@@ -56,7 +56,7 @@ from repro.telemetry import latency as lat_mod
 from repro.telemetry import sketch as sk_mod
 from repro.telemetry.controller import LoadAutoscaler
 from repro.telemetry.metrics import MetricsRegistry, TelemetryConfig
-from repro.telemetry.trace import ControlLog, Tracer, null_span
+from repro.telemetry.trace import ControlLog, Tracer, span, tracer_for
 
 
 def _linear_shard_index(axis_names):
@@ -437,14 +437,12 @@ class DistributedEngine:
             tele = self.cfg.autoscale.telemetry or TelemetryConfig()
         self.tele_cfg = tele
         self.telemetry: Optional[MetricsRegistry] = None
-        self.tracer: Optional[Tracer] = None
+        self.tracer: Optional[Tracer] = tracer_for(tele)
         self._ctl_log: Optional[ControlLog] = None
         if tele is not None:
             self.telemetry = MetricsRegistry(
                 tele, batch_size=self.cfg.batch_size)
             self._salts = self.telemetry.salts
-            if tele.trace:
-                self.tracer = Tracer()
             if tele.control_log:
                 self._ctl_log = ControlLog(tele.control_log)
         # hot-key split set: fixed-shape runtime input of the tick, so
@@ -466,11 +464,6 @@ class DistributedEngine:
     @property
     def key_bits(self) -> int:
         return int(self.key_dtype.itemsize) * 8
-
-    def _span(self, name: str, **args):
-        """Tracer span when tracing is on, else a free no-op."""
-        return self.tracer.span(name, **args) if self.tracer \
-            else null_span(**args)
 
     # ---- state ----
     def init_state(self):
@@ -955,7 +948,7 @@ class DistributedEngine:
                                          start_tick=t, handle=handle)
             outputs.extend(outs)
             t += n
-            with self._span("telemetry_observe", tick=t):
+            with span(self.tracer, "telemetry_observe", tick=t):
                 report = self.telemetry.observe(self, state)
             if "sketch" in state:
                 state = dict(state)
@@ -1043,14 +1036,15 @@ class DistributedEngine:
                 eng_tick += 1
                 if self.dur is not None and self.dur.due(
                         eng_tick, state["tables"]):
-                    with self._span("flush_boundary", tick=eng_tick,
-                                    source_tick=src_t):
+                    with span(self.tracer, "flush_boundary",
+                              tick=eng_tick, source_tick=src_t):
                         state, eng_tick = self._flush_boundary(
                             state, eng_tick, meta={"source_tick": src_t})
                     if handle is not None:
                         handle.on_frontier_advance()
                 if observe and src_t - obs_mark >= self.tele_cfg.window:
-                    with self._span("telemetry_observe", tick=src_t):
+                    with span(self.tracer, "telemetry_observe",
+                              tick=src_t):
                         report = self.telemetry.observe(self, state)
                     if handle is not None:
                         handle.on_telemetry(report)
@@ -1113,7 +1107,7 @@ class DistributedEngine:
         state = jax.device_get(self.init_state())
         state["tick"] = np.full((self.n_shards,), f_tick, np.int32)
         rh, rs = self.ring.table()
-        with self._span("recover_restore", frontier=f_tick):
+        with span(self.tracer, "recover_restore", frontier=f_tick):
             for up in self.wf.updaters():
                 recs = dur.store.scan_records(
                     up.name, now=f_tick if up.ttl else None)
@@ -1144,7 +1138,7 @@ class DistributedEngine:
             state = jax.device_put(state, self._shard_tree(state))
 
         cur = f_tick
-        with self._span("recover_replay", frontier=f_tick) as sp:
+        with span(self.tracer, "recover_replay", frontier=f_tick) as sp:
             try:
                 for tk, by_shard in merge_replay_ticks(
                         list(dur.wals) + extra_wals, offs):
@@ -1467,7 +1461,7 @@ class DistributedEngine:
         still bound to pre-migration (freed) state.
         """
         with self.read_lock:
-            with self._span("reconfigure") as sp:
+            with span(self.tracer, "reconfigure") as sp:
                 state, report = self._reconfigure_impl(
                     state, grow_to=grow_to, activate=activate,
                     deactivate=deactivate, weights=weights,

@@ -45,7 +45,7 @@ from repro.slates import table as tbl
 from repro.telemetry import latency as lat_mod
 from repro.telemetry import sketch as sk_mod
 from repro.telemetry.metrics import MetricsRegistry, TelemetryConfig
-from repro.telemetry.trace import Tracer, null_span
+from repro.telemetry.trace import Tracer, span, tracer_for
 
 
 @dataclass
@@ -246,18 +246,11 @@ class Engine:
                                         self.cfg.queue_capacity,
                                         self.cfg.batch_size)
         self.telemetry: Optional[MetricsRegistry] = None
-        self.tracer: Optional[Tracer] = None
+        self.tracer: Optional[Tracer] = tracer_for(self.cfg.telemetry)
         if self.cfg.telemetry is not None:
             self.telemetry = MetricsRegistry(
                 self.cfg.telemetry, batch_size=self.cfg.batch_size)
             self._salts = self.telemetry.salts
-            if self.cfg.telemetry.trace:
-                self.tracer = Tracer()
-
-    def _span(self, name: str, **args):
-        """Tracer span when tracing is on, else a free no-op."""
-        return self.tracer.span(name, **args) if self.tracer \
-            else null_span(**args)
 
     @property
     def key_bits(self) -> int:
@@ -337,34 +330,44 @@ class Engine:
             raise RuntimeError("overflow-stream routing did not converge "
                                "(cycle in overflow_stream config?)")
 
+        # The tick's phases carry named scopes (DESIGN.md 18.2): they
+        # name the device operations of each phase in the compiled
+        # program's metadata and the profiler's trace, and change no
+        # computation.  The updaters' phases are scoped in core/apply.
         # 1. deliver sources (visible to operators this tick; operator
         #    emissions become visible next tick — pipelined execution).
-        deliver_all(list(sources.items()))
+        with jax.named_scope("tick.queues"):
+            deliver_all(list(sources.items()))
         emitted_now: List[Tuple[str, EventBatch]] = []
 
         # 2. apply operators on their queues
         for op in wf.operators:
-            queues[op.name], batch = q_mod.dequeue(queues[op.name],
-                                                   cfg.batch_size)
+            with jax.named_scope("tick.queues"):
+                queues[op.name], batch = q_mod.dequeue(queues[op.name],
+                                                       cfg.batch_size)
             if sketch is not None and isinstance(op, Updater):
                 # key-heat telemetry: observe the keys each updater
                 # actually processes (post-routing) — pure extra state,
                 # never read by the tick itself (parity contract)
-                sketch = sk_mod.sketch_update(
-                    sketch, batch.key, batch.valid, self._salts,
-                    impl=cfg.telemetry.impl)
+                with jax.named_scope("tick.telemetry"):
+                    sketch = sk_mod.sketch_update(
+                        sketch, batch.key, batch.valid, self._salts,
+                        impl=cfg.telemetry.impl)
             if lat_hist is not None and isinstance(op, Updater):
                 # event-latency telemetry (DESIGN.md 18): the event's
                 # age at dequeue, binned into this arc's power-of-two
                 # histogram — same parity contract as the sketch
-                lat_hist[op.name] = lat_mod.hist_update(
-                    lat_hist[op.name], tick, batch.ts, batch.valid,
-                    n_buckets=cfg.telemetry.latency_buckets,
-                    impl=cfg.telemetry.impl)
+                with jax.named_scope("tick.telemetry"):
+                    lat_hist[op.name] = lat_mod.hist_update(
+                        lat_hist[op.name], tick, batch.ts, batch.valid,
+                        n_buckets=cfg.telemetry.latency_buckets,
+                        impl=cfg.telemetry.impl)
             if isinstance(op, Mapper):
-                outs = op.map_batch(batch)
-                for s, b in outs.items():
-                    emitted_now.append((s, b.mask(batch.valid & b.valid)))
+                with jax.named_scope("tick.map"):
+                    outs = op.map_batch(batch)
+                    for s, b in outs.items():
+                        emitted_now.append(
+                            (s, b.mask(batch.valid & b.valid)))
                 processed[op.name] = processed[op.name] + batch.count()
             elif isinstance(op, AssociativeUpdater):
                 tables[op.name], ems, n = apply_mod.apply_associative(
@@ -378,8 +381,9 @@ class Engine:
                 emitted_now.extend(ems.items())
                 # hotspot backpressure: re-queue over-budget run tails
                 deferred_total = deferred_total + deferred.count()
-                nq, ovf = q_mod.enqueue(queues[op.name], deferred)
-                queues[op.name] = q_mod.count_drop(nq, ovf)
+                with jax.named_scope("tick.queues"):
+                    nq, ovf = q_mod.enqueue(queues[op.name], deferred)
+                    queues[op.name] = q_mod.count_drop(nq, ovf)
                 processed[op.name] = processed[op.name] + n
             else:
                 raise TypeError(f"unknown operator type {type(op)}")
@@ -387,11 +391,13 @@ class Engine:
         # 3. TTL sweeps
         for up in wf.updaters():
             if up.ttl:
-                tables[up.name] = tbl.expire_ttl(tables[up.name], tick,
-                                                 up.ttl)
+                with jax.named_scope("apply.write"):
+                    tables[up.name] = tbl.expire_ttl(tables[up.name],
+                                                     tick, up.ttl)
 
         # 4. route this tick's emissions (visible next tick)
-        deliver_all(emitted_now)
+        with jax.named_scope("tick.queues"):
+            deliver_all(emitted_now)
 
         out_batches = {s: concat(bs) if len(bs) > 1 else bs[0]
                        for s, bs in outputs.items()}
@@ -534,27 +540,29 @@ class Engine:
         pending_obs = None      # in-flight telemetry transfer
         while t < end:
             n = min(chunk - t % chunk, end - t)
-            per_tick = [source_fn(t + i, ingest) for i in range(n)]
+            with span(self.tracer, "source_build", tick=t, n_ticks=n):
+                per_tick = [source_fn(t + i, ingest) for i in range(n)]
             if self.dur:
                 for i, srcs in enumerate(per_tick):
                     self.dur.append(eng_tick + i, srcs)  # async writer
+            with span(self.tracer, "stack_sources"):
+                stacked = stack_sources(per_tick)
             # the chunk dispatch donates (deletes) the buffers a handle
             # reader may be touching; hold the read lock from dispatch
             # until the fresh state is republished
             with self.read_lock:
-                with self._span("chunk_dispatch", tick=t, n_ticks=n):
-                    state, outs, info = self.run_chunk(
-                        state, stack_sources(per_tick), n)
+                with span(self.tracer, "chunk_dispatch", tick=t, n_ticks=n):
+                    state, outs, info = self.run_chunk(state, stacked, n)
                 # chunk is in flight: resolve the previous boundary's
                 # deferred work while the device computes
                 if pending_flush is not None:
-                    with self._span("flush_commit"):
+                    with span(self.tracer, "flush_commit"):
                         self._flush_commit(pending_flush)
                     pending_flush = None
                     if handle is not None:
                         handle.on_frontier_advance()
                 if pending_obs is not None:
-                    with self._span("observe_finish"):
+                    with span(self.tracer, "observe_finish"):
                         report = self.telemetry.finish_observe(
                             pending_obs)
                     pending_obs = None
@@ -563,8 +571,9 @@ class Engine:
                 for i in range(n):
                     outputs.append(jax.tree.map(lambda x, i=i: x[i],
                                                 outs))
-                hits_trace = jax.device_get(
-                    info["throttle_hits"])  # 1 sync
+                with span(self.tracer, "chunk_sync"):
+                    hits_trace = jax.device_get(
+                        info["throttle_hits"])  # 1 sync
                 for hits in (int(h) for h in hits_trace):
                     if hits > last_hits:     # backpressure signal
                         cur = (ingest if ingest is not None
@@ -578,7 +587,7 @@ class Engine:
                 t += n
                 eng_tick += n
                 if self.dur and self.dur.due(eng_tick, state["tables"]):
-                    with self._span("flush_begin", tick=t):
+                    with span(self.tracer, "flush_begin", tick=t):
                         state, eng_tick, pending_flush = \
                             self._flush_begin(state, eng_tick,
                                               meta={"source_tick": t})
@@ -586,7 +595,7 @@ class Engine:
                         and t - obs_mark >= self.cfg.telemetry.window):
                     # start the boundary transfer; the report resolves
                     # after the next chunk's dispatch (one-chunk lag)
-                    with self._span("observe_begin", tick=t):
+                    with span(self.tracer, "observe_begin", tick=t):
                         pending_obs = self.telemetry.begin_observe(
                             self, state)
                     state = dict(state)
@@ -598,19 +607,19 @@ class Engine:
         # trailing deferred work: the run must not return with an
         # uncommitted frontier or an unresolved report
         if pending_flush is not None:
-            with self._span("flush_commit"):
+            with span(self.tracer, "flush_commit"):
                 self._flush_commit(pending_flush)
             if handle is not None:
                 handle.on_frontier_advance()
         if pending_obs is not None:
-            with self._span("observe_finish"):
+            with span(self.tracer, "observe_finish"):
                 report = self.telemetry.finish_observe(pending_obs)
             if handle is not None:
                 handle.on_telemetry(report)
         if self.dur:
             # run() is a durable unit: every source batch it consumed is
             # on disk (and append errors surface) before control returns
-            with self._span("wal_fence"):
+            with span(self.tracer, "wal_fence"):
                 self.dur.fence()
         return state, outputs
 
@@ -716,7 +725,7 @@ class Engine:
         t_recover = time.perf_counter()
         state = self.init_state()
         state["tick"] = jnp.asarray(f_tick, jnp.int32)
-        with self._span("recover_restore", frontier=f_tick):
+        with span(self.tracer, "recover_restore", frontier=f_tick):
             for up in self.wf.updaters():
                 recs = store.scan_records(
                     up.name, now=f_tick if up.ttl else None)
@@ -744,7 +753,7 @@ class Engine:
                     state, stack_sources(group), len(group))
                 replayed += len(group)
 
-        with self._span("recover_replay", frontier=f_tick) as sp:
+        with span(self.tracer, "recover_replay", frontier=f_tick) as sp:
             cur = f_tick
             for tk, srcs in wal.replay(from_offset=f_off):
                 if tk < f_tick:

@@ -1,13 +1,17 @@
 """Host-side span tracing + the control-plane JSONL log.
 
-``Tracer`` wraps the phases the drive loops already split — chunk
-dispatch, WAL fence, flush begin/commit, telemetry observe,
-reconfigure/migration, recovery restore/replay — into Chrome
-trace-event JSON (``ph: "X"`` complete events).  ``Tracer.export``
-writes a file that loads directly in Perfetto / ``chrome://tracing``.
-The buffer is a bounded ring so tracing can stay on for long runs;
-everything here is host wall-clock around calls the drivers make
-anyway — no device syncs, no effect on the jitted tick.
+``span`` wraps the phases the drive loops already split — source
+building, stacking, chunk dispatch, the per-chunk sync, WAL fence,
+flush begin/commit, telemetry observe, reconfigure/migration, recovery
+restore/replay.  Every span enters a ``jax.profiler.TraceAnnotation``
+of its name: nearly free without a profiler session, and with one it
+lands in the device trace on the device operations' clock, so the
+device's idle time can be put down to the phase the host was in.
+With ``TelemetryConfig(trace=True)`` the span is also recorded in the
+engine's ``Tracer``, a bounded ring exported as Chrome trace-event JSON
+(``ph: "X"`` complete events) that loads directly in Perfetto /
+``chrome://tracing``.  Everything here is host work around calls the
+drivers make anyway — no device syncs, no effect on the jitted tick.
 
 ``ControlLog`` is the autoscaler's flight recorder: one JSON line per
 observe→decide→act cycle (report summary, decision + reason, applied
@@ -24,6 +28,7 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
 
@@ -75,12 +80,6 @@ class Tracer:
                         "tid": threading.get_ident() % 100000,
                         "args": json_safe(a)})
 
-    def instant(self, name: str, cat: str = "engine", **args):
-        self._push({"name": name, "cat": cat, "ph": "i", "s": "t",
-                    "ts": self._now_us(), "pid": 0,
-                    "tid": threading.get_ident() % 100000,
-                    "args": json_safe(args)})
-
     def _push(self, ev: Dict[str, Any]):
         with self._lock:
             self._events.append(ev)
@@ -101,15 +100,24 @@ class Tracer:
         return path
 
 
-def null_span(**args):
-    """Stand-in for ``Tracer.span`` when tracing is off: yields the
-    same mutable args dict, records nothing."""
-    return _null_span(args)
+def tracer_for(telemetry) -> Optional[Tracer]:
+    """The span ring of an engine with ``telemetry`` config: a
+    ``Tracer`` when ``telemetry.trace`` is set, else None."""
+    return Tracer() if telemetry is not None and telemetry.trace else None
 
 
 @contextmanager
-def _null_span(args):
-    yield args
+def span(tracer: Optional[Tracer], name: str, **args):
+    """One phase of a drive loop: a profiler annotation named ``name``
+    always, and a ``tracer`` span with ``args`` when ``tracer`` is set.
+    Yields the mutable args dict either way, so outcomes measured
+    inside the span (a migration's ``pause_s``) land on the span."""
+    with jax.profiler.TraceAnnotation(name):
+        if tracer is None:
+            yield args
+        else:
+            with tracer.span(name, **args) as a:
+                yield a
 
 
 class ControlLog:

@@ -293,9 +293,98 @@ def test_engine_run_emits_phase_spans():
             np.arange(8) + t, ts=np.full(8, t, np.int32))}
         eng.run(eng.init_state(), src, 8)
         names = {e["name"] for e in eng.tracer.events()}
-        assert {"chunk_dispatch", "wal_fence", "flush_begin",
+        assert {"source_build", "stack_sources", "chunk_dispatch",
+                "chunk_sync", "wal_fence", "flush_begin",
                 "flush_commit"} <= names, names
         eng.close()
+
+
+TICK_SCOPES = ("tick.queues", "tick.telemetry", "tick.map", "apply.sort",
+               "apply.probe", "apply.pack", "apply.write")
+
+
+def _counting_app(capacity=256, batch=32):
+    """Paper Examples 1/4 at a tiny size: a mapper, a counter and an
+    [8]-lane sum, both on the packed path."""
+    from repro import App, EventBatch, RuntimeConfig, ops
+    app = App("scoped")
+    checkins = app.source("S1", {"x": ((), jnp.float32)})
+
+    @app.mapper(checkins, out="S2")
+    def parse(b):
+        return EventBatch(sid=b.sid, ts=b.ts + 1, key=b.key,
+                          value=b.value, valid=b.valid)
+
+    parsed = app.stream("S2")
+    parsed.update(ops.counter("U1", table_capacity=capacity))
+
+    @app.updater(parsed, slate={"v": ((8,), jnp.float32)}, name="UV",
+                 table_capacity=capacity)
+    def lanes(b):
+        return {"v": jnp.ones((b.key.shape[0], 8), jnp.float32)}
+
+    app.start(RuntimeConfig(batch_size=batch, chunk_size=2, fused="ref",
+                            telemetry=TelemetryConfig(impl="jnp")))
+
+    def source(t, _mx=None):
+        return {"S1": EventBatch(
+            sid=jnp.zeros(batch, jnp.int32),
+            ts=jnp.full(batch, t, jnp.int32),
+            key=jnp.arange(batch, dtype=jnp.int32) * 7 + t,
+            value={"x": jnp.ones(batch, jnp.float32)},
+            valid=jnp.ones(batch, bool))}
+    return app, source
+
+
+def test_tick_phases_are_named_in_the_compiled_chunk():
+    """Every phase of the tick carries its named scope into the compiled
+    chunk's op_name metadata, and every claim scatter of
+    ``insert_or_find`` (INSERT_ROUNDS per updater, into the s32[C] key
+    array) lies under ``apply.probe``."""
+    from repro.slates.table import INSERT_ROUNDS
+    cap = 256
+    app, source = _counting_app(capacity=cap)
+    eng = app.engine
+    hlo = eng._chunk.lower(
+        eng.init_state(), stack_sources([source(0), source(1)]),
+        jnp.int32(32), n_ticks=2, adapt=False,
+        throttle_floor=8).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    for scope in TICK_SCOPES:
+        assert any(f"/{scope}/" in n for n in names), scope
+    claims = [ln for ln in hlo.splitlines()
+              if re.search(rf"= s32\[{cap}\]\S* scatter\(", ln)
+              and "/apply.probe/" in ln]
+    assert len(claims) == INSERT_ROUNDS * 2, claims
+
+
+def test_engine_run_spans_reach_the_profiler(tmp_path):
+    """With no span ring (``trace`` off), the drive loop's spans still
+    land in a profiler session, nested in the caller's annotation."""
+    from jax.profiler import ProfileData
+    app, source = _counting_app()
+    eng = app.engine
+    assert eng.tracer is None
+    state = eng.init_state()
+    state, _ = eng.run(state, source, 2)          # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("caller.run"):
+        state, _ = eng.run(state, source, 4)
+        jax.block_until_ready(state)
+    jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events]
+    outer = [(a, b) for n, a, b in spans if n == "caller.run"]
+    assert len(outer) == 1
+    lo, hi = outer[0]
+    for name in ("source_build", "stack_sources", "chunk_dispatch",
+                 "chunk_sync"):
+        inside = [(a, b) for n, a, b in spans if n == name]
+        assert len(inside) == 2, (name, inside)   # two 2-tick chunks
+        assert all(lo <= a <= b <= hi for a, b in inside), name
 
 
 def test_control_log_jsonl(tmp_path):
