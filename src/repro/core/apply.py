@@ -26,6 +26,7 @@ and ``apply.write`` (slate read/merge/write, the ts/dirty scatter).  The
 """
 from __future__ import annotations
 
+from dataclasses import replace
 from typing import Dict, Tuple
 
 import jax
@@ -229,8 +230,7 @@ def _apply_associative_fused(updater: AssociativeUpdater,
         # bookkeeping scatter (ts / dirty), same slots write_slates hits
         ts = table.ts.at[safe].set(tick, mode="drop")
         dirty = table.dirty.at[safe].set(True, mode="drop")
-    table = tbl.SlateTable(keys=table.keys, ts=ts, dirty=dirty, vals=vals,
-                           dropped=table.dropped)
+    table = replace(table, ts=ts, dirty=dirty, vals=vals)
     return table, {}, batch.count()
 
 

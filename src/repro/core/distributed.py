@@ -228,8 +228,8 @@ def exchange_rows(t: tbl.SlateTable, dest_salt: int, ring_hashes,
     # the full fold; singleton runs keep their original ts/dirty
     rep = vs & ~jnp.concatenate([prev_same[1:], jnp.zeros((1,), bool)])
 
-    fresh = tbl.SlateTable(
-        keys=jnp.full((C,), tbl.EMPTY, t.keys.dtype),
+    fresh = replace(
+        t, keys=jnp.full((C,), tbl.EMPTY, t.keys.dtype),
         ts=jnp.zeros((C,), jnp.int32),
         dirty=jnp.zeros((C,), bool),
         vals=jax.tree.map(jnp.zeros_like, t.vals),
@@ -240,8 +240,8 @@ def exchange_rows(t: tbl.SlateTable, dest_salt: int, ring_hashes,
         lambda dst, src: dst.at[safe].set(src.astype(dst.dtype),
                                           mode="drop"),
         fresh.vals, fvals)
-    new = tbl.SlateTable(
-        keys=fresh.keys,
+    new = replace(
+        fresh,
         ts=fresh.ts.at[safe].set(fts, mode="drop"),
         dirty=fresh.dirty.at[safe].set(fdirty, mode="drop"),
         vals=new_vals,
@@ -848,9 +848,8 @@ class DistributedEngine:
                 dur.flusher.flush_rows(
                     up.name, keys[sh][idx], ts[sh][idx],
                     jax.tree.map(lambda v: v[sh][idx], vals), up.ttl)
-            new_tables[up.name] = tbl.SlateTable(
-                keys=t.keys, ts=t.ts, dirty=jnp.zeros_like(t.dirty),
-                vals=t.vals, dropped=t.dropped)
+            new_tables[up.name] = replace(
+                t, dirty=jnp.zeros_like(t.dirty))
         state = dict(state)
         state["tables"] = new_tables
         dur.record_frontier(tick, meta=meta)
@@ -1236,9 +1235,7 @@ class DistributedEngine:
                 jnp.full_like(t.keys[shard], tbl.EMPTY))
             dirty = t.dirty.at[shard].set(
                 jnp.zeros_like(t.dirty[shard]))
-            new_tables[name] = tbl.SlateTable(
-                keys=keys, ts=t.ts, dirty=dirty, vals=t.vals,
-                dropped=t.dropped)
+            new_tables[name] = replace(t, keys=keys, dirty=dirty)
         state["tables"] = new_tables
         return state
 
@@ -1804,11 +1801,12 @@ class DistributedEngine:
         migrators the caller runs next scan every old slice and rebuild
         at the new shard count.  Per-slot *lifetime* counters — the
         count-min sketch's counts/total/sample_n, ``processed``,
-        ``exchange_dropped``, ``throttle_hits``, and the table/queue
-        ``dropped`` tallies — are folded from the dead slots into the
-        first survivor before slicing, so ``TelemetryReport`` lifetime
-        counts stay exact across a compaction (the sketch key-sample
-        ring is positional, not a counter: it is sliced, not summed).
+        ``exchange_dropped``, ``throttle_hits``, the table/queue
+        ``dropped`` tallies and the tables' ``claim_rounds`` — are
+        folded from the dead slots into the first survivor before
+        slicing, so ``TelemetryReport`` lifetime counts stay exact
+        across a compaction (the sketch key-sample ring is positional,
+        not a counter: it is sliced, not summed).
         Returns ``(host, slot_map)`` where ``slot_map[d]`` is the old
         slot renumbered to new slot ``d``; durability shrinks its WAL
         set via ``resize`` after the flush barrier that preceded us."""
@@ -1857,24 +1855,23 @@ class DistributedEngine:
                             for nm, lf in val.items()}
             else:
                 out[key] = jax.tree.map(sel, val)
-        # table/queue drop tallies stay at the old size for the host
-        # migrators, which inherit ``dropped[slot_map[d]]`` — park the
-        # dead slots' counts on the first survivor so they carry
+        # table/queue tallies stay at the old size for the host
+        # migrators, which inherit ``dropped[slot_map[d]]`` (and the
+        # tables' ``claim_rounds``) — park the dead slots' counts on the
+        # first survivor so they carry
         if dead.size:
+            def park(leaf):
+                a = np.asarray(leaf).copy()
+                a[idx[0]] += a[dead].sum(axis=0).astype(a.dtype)
+                a[dead] = 0
+                return a
+
             for name, t in host["tables"].items():
-                drop = np.asarray(t.dropped).copy()
-                drop[idx[0]] += drop[dead].sum(axis=0).astype(drop.dtype)
-                drop[dead] = 0
-                out["tables"][name] = tbl.SlateTable(
-                    keys=t.keys, ts=t.ts, dirty=t.dirty, vals=t.vals,
-                    dropped=drop)
+                out["tables"][name] = replace(
+                    t, dropped=park(t.dropped),
+                    claim_rounds=park(t.claim_rounds))
             for name, q in host["queues"].items():
-                drop = np.asarray(q.dropped).copy()
-                drop[idx[0]] += drop[dead].sum(axis=0).astype(drop.dtype)
-                drop[dead] = 0
-                out["queues"][name] = q_mod.QueueState(
-                    buf=q.buf, head=q.head, size=q.size, dropped=drop,
-                    peak=q.peak)
+                out["queues"][name] = replace(q, dropped=park(q.dropped))
         tick = int(np.asarray(host["tick"]).max())
         out["tick"] = np.full((k,), tick, np.int32)
         return out, [int(a) for a in actives]
@@ -1898,9 +1895,7 @@ class DistributedEngine:
         for name, t in out["tables"].items():
             keys = np.asarray(t.keys)
             keys[old_n:] = -1                   # new slots start empty
-            new_tables[name] = tbl.SlateTable(
-                keys=keys, ts=t.ts, dirty=t.dirty, vals=t.vals,
-                dropped=t.dropped)
+            new_tables[name] = replace(t, keys=keys)
         out["tables"] = new_tables
         return out
 
@@ -1920,7 +1915,8 @@ class DistributedEngine:
         The input table's leading dim may exceed ``self.n_shards``
         (slot compaction): all old slices are scanned, the rebuild is
         stacked at the new count, and ``slot_map[d]`` names the old
-        slot whose ``dropped`` tally new slot ``d`` inherits."""
+        slot whose ``dropped`` and ``claim_rounds`` tallies new slot ``d``
+        inherits."""
         moved: Dict[str, int] = {}
         n = self.n_shards
         for up in self.wf.updaters():
@@ -1933,6 +1929,7 @@ class DistributedEngine:
             sh, slot = np.nonzero(keys != -1)
             moved[up.name] = 0
             drop = np.array(t.dropped)
+            claims = np.array(t.claim_rounds)
             if len(sh) == 0:
                 if keys.shape[0] != n:
                     out = []
@@ -1940,11 +1937,12 @@ class DistributedEngine:
                         loc = tbl.make_table(up.table_capacity,
                                              up.slate_spec(),
                                              key_dtype=self.key_dtype)
-                        out.append(jax.device_get(tbl.SlateTable(
-                            keys=loc.keys, ts=loc.ts, dirty=loc.dirty,
-                            vals=loc.vals,
+                        out.append(jax.device_get(replace(
+                            loc,
                             dropped=jnp.asarray(int(drop[smap[d]]),
-                                                jnp.int32))))
+                                                jnp.int32),
+                            claim_rounds=jnp.asarray(
+                                int(claims[smap[d]]), jnp.int32))))
                     tables[up.name] = jax.tree.map(
                         lambda *xs: np.stack(xs), *out)
                 continue
@@ -1959,7 +1957,8 @@ class DistributedEngine:
             for d in range(n):
                 pick = np.nonzero(owner == d)[0]
                 loc = self._build_local_table(
-                    up, int(drop[smap[d]]), rkeys[pick], ts[pick],
+                    up, int(drop[smap[d]]), int(claims[smap[d]]),
+                    rkeys[pick], ts[pick],
                     dirty[pick],
                     jax.tree.map(lambda v: v[pick], vals))
                 out[d] = jax.device_get(loc)
@@ -1967,10 +1966,13 @@ class DistributedEngine:
                 lambda *xs: np.stack(xs), *out)
         return moved
 
-    def _build_local_table(self, up, dropped0: int, in_keys, in_ts,
-                           in_dirty, in_vals) -> tbl.SlateTable:
+    def _build_local_table(self, up, dropped0: int, claims0: int,
+                           in_keys, in_ts, in_dirty, in_vals
+                           ) -> tbl.SlateTable:
         """One shard's fresh table from migrated rows (dup keys folded
-        with the updater's combine, clean rows stay clean)."""
+        with the updater's combine, clean rows stay clean); its
+        ``dropped`` and ``claim_rounds`` tallies continue from
+        ``dropped0`` and ``claims0``."""
         combine = getattr(up, "combine", None)
         # fold duplicate keys (two-choice partials converging here)
         first: Dict[int, int] = {}
@@ -1996,8 +1998,10 @@ class DistributedEngine:
         in_ts, in_dirty = in_ts[uniq], in_dirty[uniq]
         in_vals = jax.tree.map(lambda v: v[uniq], in_vals)
 
-        local = tbl.make_table(up.table_capacity, up.slate_spec(),
-                               key_dtype=self.key_dtype)
+        local = replace(
+            tbl.make_table(up.table_capacity, up.slate_spec(),
+                           key_dtype=self.key_dtype),
+            claim_rounds=jnp.asarray(claims0, jnp.int32))
         drops = 0
         for i in range(0, len(in_keys), 256):
             k = jnp.asarray(in_keys[i:i + 256], self.key_dtype)
@@ -2012,15 +2016,11 @@ class DistributedEngine:
             # the move stay clean (they still match the store)
             keep_clean = jnp.asarray(~in_dirty[i:i + 256]) & placed
             safe = jnp.where(keep_clean, slot, local.capacity)
-            local = tbl.SlateTable(
-                keys=local.keys, ts=local.ts,
-                dirty=local.dirty.at[safe].set(False, mode="drop"),
-                vals=local.vals, dropped=local.dropped)
+            local = replace(
+                local, dirty=local.dirty.at[safe].set(False, mode="drop"))
             drops += int(jax.device_get((~placed).sum()))
-        return tbl.SlateTable(
-            keys=local.keys, ts=local.ts, dirty=local.dirty,
-            vals=local.vals,
-            dropped=jnp.asarray(dropped0 + drops, jnp.int32))
+        return replace(local,
+                       dropped=jnp.asarray(dropped0 + drops, jnp.int32))
 
     def _migrate_queues_host(self, queues,
                              slot_map=None) -> Dict[str, int]:

@@ -826,4 +826,6 @@ class Engine:
                                 for k, t in state["tables"].items()},
             "table_dropped": {k: int(g(t.dropped))
                               for k, t in state["tables"].items()},
+            "table_claim_rounds": {k: int(g(t.claim_rounds))
+                                   for k, t in state["tables"].items()},
         }

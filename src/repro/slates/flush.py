@@ -14,7 +14,7 @@ import json
 import os
 import queue as pyqueue
 import threading
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import List, Optional, Sequence, Union
 
 import jax
@@ -101,10 +101,7 @@ def begin_dirty_snapshot(table: tbl.SlateTable):
              jnp.copy(table.ts), jax.tree.map(jnp.copy, table.vals))
     for leaf in jax.tree.leaves(token):
         leaf.copy_to_host_async()
-    cleared = tbl.SlateTable(
-        keys=table.keys, ts=table.ts,
-        dirty=jnp.zeros_like(table.dirty),
-        vals=table.vals, dropped=table.dropped)
+    cleared = replace(table, dirty=jnp.zeros_like(table.dirty))
     return token, cleared
 
 
@@ -149,9 +146,7 @@ def restore_into(table: tbl.SlateTable, keys: np.ndarray, slates,
     table = tbl.write_slates(table, slot, placed, vals,
                              jnp.asarray(ts, jnp.int32))
     # restored slates are clean (they came *from* the store)
-    return tbl.SlateTable(keys=table.keys, ts=table.ts,
-                          dirty=jnp.zeros_like(table.dirty),
-                          vals=table.vals, dropped=table.dropped)
+    return replace(table, dirty=jnp.zeros_like(table.dirty))
 
 
 class Flusher:

@@ -6,13 +6,16 @@ in HBM as struct-of-arrays so the updater hot loop is pure gather /
 compute / scatter.
 
 Collision handling is double hashing with a static probe budget; batch
-inserts resolve intra-batch slot races with bounded retry rounds.  Keys
-that cannot be placed are *dropped and counted* — bounded-resource loss
-semantics, exactly how Muppet treats overload (sections 4.3, 5).  TTL and
-dirty bits mirror the paper's flush / garbage-collection knobs.
+inserts look every key up once, then resolve intra-batch slot races with
+bounded claim rounds that run only while some key still lacks a slot, so
+a batch whose keys are all present costs one lookup.  Keys that cannot
+be placed are *dropped and counted* — bounded-resource loss semantics,
+exactly how Muppet treats overload (sections 4.3, 5).  TTL and dirty bits
+mirror the paper's flush / garbage-collection knobs.
 """
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Tuple
 
@@ -23,7 +26,7 @@ from repro.core.hashing import hash_key
 
 EMPTY = jnp.int32(-1)
 PROBES = 8          # static probe budget per lookup
-INSERT_ROUNDS = 4   # bounded retry rounds for batch insert
+INSERT_ROUNDS = 4   # bounded claim rounds for batch insert
 
 
 @jax.tree_util.register_dataclass
@@ -34,6 +37,7 @@ class SlateTable:
     dirty: jnp.ndarray     # bool [C] updated since last flush
     vals: Any              # pytree, leaves [C, ...]
     dropped: jnp.ndarray   # int32 [] lifetime insert-failure count
+    claim_rounds: jnp.ndarray  # int32 [] lifetime claim rounds run
 
     @property
     def capacity(self) -> int:
@@ -55,6 +59,7 @@ def make_table(capacity: int, value_spec: Dict[str, Any],
         dirty=jnp.zeros((capacity,), bool),
         vals=vals,
         dropped=jnp.zeros((), jnp.int32),
+        claim_rounds=jnp.zeros((), jnp.int32),
     )
 
 
@@ -98,37 +103,57 @@ def insert_or_find(table: SlateTable, query, valid) -> Tuple[
         SlateTable, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Place unique ``query`` keys (masked by ``valid``).
 
-    Returns (table, slot [B], found_existing [B], placed [B]).  New keys
-    claim empty slots; intra-batch races on the same empty slot resolve
-    over INSERT_ROUNDS retries; stragglers are dropped (counted).
+    Returns (table, slot [B], found_existing [B], placed [B]).  Every key
+    is looked up once.  A claim round — scatter the keys that need a slot
+    into their empty candidate slots, read back who owns each, look up
+    again — runs only while some pending key still needs one, at most
+    INSERT_ROUNDS times; intra-batch races on one empty slot resolve over
+    those rounds and stragglers are dropped (counted).  A round with
+    nothing to claim settles the found keys and leaves every other
+    pending key with a spent probe budget, so the rounds it would be
+    followed by change nothing: the answer is the one INSERT_ROUNDS
+    unconditional rounds give.  ``claim_rounds`` counts the rounds run.
     Caller must guarantee uniqueness of valid keys (dedup upstream).
     """
-    keys_arr = table.keys
-    slot = jnp.full(query.shape, -1, jnp.int32)
-    placed = jnp.zeros(query.shape, bool)
-    found = jnp.zeros(query.shape, bool)
-    pending = valid
+    cap = table.capacity
 
-    for _ in range(INSERT_ROUNDS):
-        cand_slot, cand_found = _lookup_keys(keys_arr, query,
-                                             table.capacity)
+    def needs_slot(carry):
+        rounds, _, _, _, pending, cand_slot, cand_found = carry
+        return (rounds < INSERT_ROUNDS) & jnp.any(
+            pending & (cand_slot >= 0) & ~cand_found)
+
+    def claim_round(carry):
+        rounds, keys_arr, slot, found, pending, cand_slot, cand_found = carry
         want = pending & (cand_slot >= 0)
         # claim: scatter key ids into candidate slots; later writers win,
         # so read back to see who actually owns the slot
-        safe_slot = jnp.where(want & ~cand_found, cand_slot, table.capacity)
-        keys_try = keys_arr.at[safe_slot].set(query, mode="drop")
-        owner_ok = keys_try[jnp.clip(cand_slot, 0, table.capacity - 1)] == query
+        safe_slot = jnp.where(want & ~cand_found, cand_slot, cap)
+        keys_arr = keys_arr.at[safe_slot].set(query, mode="drop")
+        owner_ok = keys_arr[jnp.clip(cand_slot, 0, cap - 1)] == query
         success = want & (cand_found | owner_ok)
         slot = jnp.where(success, cand_slot, slot)
         found = found | (want & cand_found)
-        placed = placed | success
         pending = pending & ~success
-        keys_arr = keys_try
+        return (rounds + 1, keys_arr, slot, found, pending,
+                *_lookup_keys(keys_arr, query, cap))
 
-    dropped = table.dropped + jnp.sum(pending, dtype=jnp.int32)
-    new_table = SlateTable(keys=keys_arr, ts=table.ts, dirty=table.dirty,
-                           vals=table.vals, dropped=dropped)
-    return new_table, slot, found, placed
+    carry = (jnp.int32(0), table.keys, jnp.full(query.shape, -1, jnp.int32),
+             jnp.zeros(query.shape, bool), valid,
+             *_lookup_keys(table.keys, query, cap))
+    rounds, keys_arr, slot, found, pending, cand_slot, cand_found = (
+        jax.lax.while_loop(needs_slot, claim_round, carry))
+    # the round after the last claim, if one is left, only settles the
+    # keys it finds: nothing needs a slot, so want == pending & found
+    hit = pending & cand_found & (rounds < INSERT_ROUNDS)
+    slot = jnp.where(hit, cand_slot, slot)
+    found = found | hit
+    pending = pending & ~hit
+
+    new_table = dataclasses.replace(
+        table, keys=keys_arr,
+        dropped=table.dropped + jnp.sum(pending, dtype=jnp.int32),
+        claim_rounds=table.claim_rounds + rounds)
+    return new_table, slot, found, valid & ~pending
 
 
 def _lookup_keys(keys_arr, query, capacity):
@@ -164,8 +189,7 @@ def write_slates(table: SlateTable, slot, ok, new_vals, tick) -> SlateTable:
         table.vals, new_vals)
     ts = table.ts.at[safe].set(tick, mode="drop")
     dirty = table.dirty.at[safe].set(True, mode="drop")
-    return SlateTable(keys=table.keys, ts=ts, dirty=dirty, vals=vals,
-                      dropped=table.dropped)
+    return dataclasses.replace(table, ts=ts, dirty=dirty, vals=vals)
 
 
 def expire_ttl(table: SlateTable, now, ttl: int) -> SlateTable:
@@ -174,8 +198,7 @@ def expire_ttl(table: SlateTable, now, ttl: int) -> SlateTable:
     keys = jnp.where(dead, jnp.asarray(EMPTY, table.keys.dtype),
                      table.keys)
     dirty = jnp.where(dead, False, table.dirty)
-    return SlateTable(keys=keys, ts=table.ts, dirty=dirty, vals=table.vals,
-                      dropped=table.dropped)
+    return dataclasses.replace(table, keys=keys, dirty=dirty)
 
 
 def _bshape(mask, like):

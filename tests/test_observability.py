@@ -339,9 +339,8 @@ def _counting_app(capacity=256, batch=32):
 def test_tick_phases_are_named_in_the_compiled_chunk():
     """Every phase of the tick carries its named scope into the compiled
     chunk's op_name metadata, and every claim scatter of
-    ``insert_or_find`` (INSERT_ROUNDS per updater, into the s32[C] key
-    array) lies under ``apply.probe``."""
-    from repro.slates.table import INSERT_ROUNDS
+    ``insert_or_find`` (one per updater, in the body of its claim-round
+    loop, into the s32[C] key array) lies under ``apply.probe``."""
     cap = 256
     app, source = _counting_app(capacity=cap)
     eng = app.engine
@@ -355,7 +354,24 @@ def test_tick_phases_are_named_in_the_compiled_chunk():
     claims = [ln for ln in hlo.splitlines()
               if re.search(rf"= s32\[{cap}\]\S* scatter\(", ln)
               and "/apply.probe/" in ln]
-    assert len(claims) == INSERT_ROUNDS * 2, claims
+    assert len(claims) == 2, claims
+
+
+def test_claim_rounds_run_only_for_missing_keys():
+    """``table_claim_rounds`` stays put over ticks whose keys are all in
+    the tables and rises over ticks that insert."""
+    app, source = _counting_app()
+    eng = app.engine
+    same = lambda t, _mx=None: source(0)
+    state, _ = eng.run(eng.init_state(), same, 4)
+    loaded = eng.stats(state)["table_claim_rounds"]
+    assert set(loaded) == {"U1", "UV"} and min(loaded.values()) > 0
+    state, _ = eng.run(state, same, 4)
+    assert eng.stats(state)["table_claim_rounds"] == loaded
+    state, _ = eng.run(state, lambda t, _mx=None: source(1000), 4)
+    grown = eng.stats(state)["table_claim_rounds"]
+    assert all(grown[k] > loaded[k] for k in loaded)
+    assert sum(eng.stats(state)["table_dropped"].values()) == 0
 
 
 def test_engine_run_spans_reach_the_profiler(tmp_path):

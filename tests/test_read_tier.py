@@ -9,6 +9,7 @@ rows — plus the off-engine tiers: ``SlateReplica`` staleness bounds
 Multi-shard coverage runs in subprocesses (same pattern as
 test_elasticity) so the main pytest process keeps the real single
 device."""
+import dataclasses
 import os
 import subprocess
 import sys
@@ -42,8 +43,7 @@ def _filled_table(n_rows=200, cap=512, d=8, seed=0):
         t, jnp.asarray(keys), jnp.ones(n_rows, bool))
     vals = {"v": t.vals["v"].at[slot].set(
         rng.normal(size=(n_rows, d)).astype(np.float32))}
-    t = tbl.SlateTable(keys=t.keys, ts=t.ts, dirty=t.dirty, vals=vals,
-                       dropped=t.dropped)
+    t = dataclasses.replace(t, vals=vals)
     assert bool(np.asarray(placed).all())
     return t, keys
 
