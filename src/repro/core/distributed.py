@@ -425,9 +425,6 @@ class DistributedEngine:
         self._live_handle = None
         self._load_mark = np.zeros(self.n_shards)  # rebalance window base
         self.tick_cursor = 0      # post-run() *source* cursor
-        self.dur: Optional[EngineDurability] = None
-        if self.cfg.durability is not None:
-            self.attach_durability(self.cfg.durability)
         # telemetry (DESIGN.md 13): a per-shard count-min sketch in the
         # jitted tick + the windowed registry; a closed-loop controller
         # implies it even when cfg.telemetry is unset
@@ -438,6 +435,9 @@ class DistributedEngine:
         self.tele_cfg = tele
         self.telemetry: Optional[MetricsRegistry] = None
         self.tracer: Optional[Tracer] = tracer_for(tele)
+        self.dur: Optional[EngineDurability] = None
+        if self.cfg.durability is not None:
+            self.attach_durability(self.cfg.durability)
         self._ctl_log: Optional[ControlLog] = None
         if tele is not None:
             self.telemetry = MetricsRegistry(
@@ -764,7 +764,8 @@ class DistributedEngine:
         self.dur = EngineDurability(cfg, self.wf,
                                     self.cfg.queue_capacity,
                                     self.cfg.batch_size,
-                                    n_shards=self.n_shards)
+                                    n_shards=self.n_shards,
+                                    tracer=self.tracer)
 
     def append_sources(self, tick: int, sources: Dict[str, EventBatch]):
         """Write-ahead: log each shard's slice of the [n_shards, B]
@@ -1108,11 +1109,11 @@ class DistributedEngine:
         rh, rs = self.ring.table()
         with span(self.tracer, "recover_restore", frontier=f_tick):
             for up in self.wf.updaters():
-                recs = dur.store.scan_records(
+                keys, ts, slates = dur.store.scan_columns(
                     up.name, now=f_tick if up.ttl else None)
-                if not recs:
+                if not keys.size:
                     continue
-                ks = np.asarray(sorted(recs), self.key_dtype)
+                ks = keys.astype(self.key_dtype)
                 shard_of = np.asarray(jax.device_get(
                     route(jnp.asarray(ks), _salt(up.name), rh, rs)))
                 t = state["tables"][up.name]
@@ -1121,13 +1122,10 @@ class DistributedEngine:
                     local = jax.tree.map(lambda x: jnp.asarray(x[sh]), t)
                     sel = np.nonzero(shard_of == sh)[0]
                     if len(sel):
-                        ts = np.asarray(
-                            [recs[int(k)][0] for k in ks[sel]], np.int32)
-                        slates = jax.tree.map(
-                            lambda *r: np.stack(r),
-                            *[recs[int(k)][1] for k in ks[sel]])
-                        local = flush_mod.restore_into(local, ks[sel],
-                                                       slates, ts)
+                        local = flush_mod.restore_into(
+                            local, ks[sel],
+                            jax.tree.map(lambda v: v[sel], slates),
+                            ts[sel].astype(np.int32))
                     per_shard.append(jax.device_get(local))
                 state["tables"][up.name] = jax.tree.map(
                     lambda *xs: np.stack(xs), *per_shard)
@@ -2115,6 +2113,7 @@ class DistributedEngine:
                               for k, q in state["queues"].items()},
             "table_occupancy": {k: int(g(t.occupancy()).sum())
                                 for k, t in state["tables"].items()},
+            **(self.dur.counters() if self.dur is not None else {}),
         }
 
     def read_slate(self, state, updater: str, key: int, *, merge=None):

@@ -33,9 +33,11 @@ import threading
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
+from repro.slates import flush as flush_mod
 from repro.slates.flush import FlushConfig, Flusher, FlushFrontier
 from repro.slates.kvstore import KVStore
 from repro.slates.wal import WriteAheadLog
+from repro.telemetry.trace import span
 
 
 @dataclass
@@ -114,14 +116,16 @@ class EngineDurability:
 
     def __init__(self, cfg: DurabilityConfig, workflow,
                  queue_capacity: int, batch_size: int,
-                 n_shards: Optional[int] = None):
+                 n_shards: Optional[int] = None, tracer=None):
         self.cfg = cfg
         self.wf = workflow
         self.n_shards = n_shards
+        self.tracer = tracer
         os.makedirs(cfg.dir, exist_ok=True)
         self.store = cfg.make_store()
         self.flusher = Flusher(self.store, cfg.flush,
-                               track_deltas=cfg.track_flush_deltas)
+                               track_deltas=cfg.track_flush_deltas,
+                               tracer=tracer)
         if n_shards is None:
             self.wals = [WriteAheadLog(cfg.wal_path(), sync=cfg.sync_wal)]
         else:
@@ -129,6 +133,10 @@ class EngineDurability:
                          for s in range(n_shards)]
         self.frontier = FlushFrontier.load(cfg.frontier_path()) or \
             FlushFrontier(tick=0, wal_offset=self._offsets())
+        # a crash between a flush's store writes and its frontier save
+        # leaves blocks the replay from this frontier would apply again
+        self.store.discard_after(self.frontier.store_seq)
+        self.store.seal(self.frontier.store_seq)
         self.slack = cfg.replay_slack if cfg.replay_slack is not None \
             else auto_replay_slack(workflow, queue_capacity, batch_size)
         # tick -> per-wal offsets *before* that tick's appends; needed to
@@ -179,7 +187,8 @@ class EngineDurability:
                       if t < tick - 2 * self.slack]:
                 del self._tick_offsets[t]
         if sources:
-            self.wals[shard].append(tick, sources)
+            with span(self.tracer, "wal_append", tick=tick, shard=shard):
+                self.wals[shard].append(tick, sources)
 
     def append(self, tick: int, sources, shard: Optional[int] = None):
         """Log one tick's sources (single-shard) or one shard's slice.
@@ -264,8 +273,11 @@ class EngineDurability:
         self.frontier = FlushFrontier(
             tick=f_tick,
             wal_offset=f_offs[0] if self.n_shards is None else f_offs,
-            meta=meta if meta is not None else self.frontier.meta)
+            meta=meta if meta is not None else self.frontier.meta,
+            store_seq=self.store.last_seq)
         self.frontier.save(self.cfg.frontier_path())
+        self.store.seal(self.frontier.store_seq)
+        self.flusher.merge_soon()
         if self.cfg.truncate_wal:
             for w, off in zip(self.wals, f_offs):
                 w.truncate_before(off)
@@ -277,6 +289,18 @@ class EngineDurability:
         is empty, so the frontier is exactly ``tick``; without it the
         frontier is backdated by ``replay_slack`` ticks."""
         self.commit_frontier(self.begin_frontier(tick), meta=meta)
+
+    def counters(self) -> Dict[str, object]:
+        """Cumulative write counters: rows flushed per updater, bytes of
+        store blocks written by flushes and by compaction (over
+        replicas), and bytes appended to the WAL(s)."""
+        rows = dict(self.flusher.rows_written)
+        return {"flush_rows": {u.name: rows.get(u.name, 0)
+                               for u in self.wf.updaters()},
+                "store_bytes_written": self.store.bytes_written,
+                "store_bytes_compacted": self.store.bytes_compacted,
+                "wal_bytes_written": sum(w.bytes_written
+                                         for w in self.wals)}
 
     def frontier_offsets(self) -> List[int]:
         off = self.frontier.wal_offset
@@ -311,8 +335,21 @@ class EngineDurability:
         self.n_shards = n_shards
         self.frontier = FlushFrontier(tick=self.frontier.tick,
                                       wal_offset=offs,
-                                      meta=self.frontier.meta)
+                                      meta=self.frontier.meta,
+                                      store_seq=self.frontier.store_seq)
         self.frontier.save(self.cfg.frontier_path())
+
+    def halt(self):
+        """Stop the log's writer and the flusher's threads with no fence,
+        no store flush and no frontier: the directory as a process crash
+        leaves it, with no thread of this runtime still writing to it
+        (an append, write or merge under way finishes first).  A crash
+        check recovers a fresh engine from it while this one stays
+        open; ``close`` after ``halt`` writes nothing more."""
+        flush_mod.drop_queued(self._wq)
+        self._wq.put(None)
+        self._wthread.join(timeout=5)
+        self.flusher.halt()
 
     def close(self):
         try:

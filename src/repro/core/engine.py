@@ -240,13 +240,16 @@ class Engine:
         self._chunk = jax.jit(self._chunk_impl, donate_argnums=(0,),
                               static_argnames=("n_ticks", "adapt",
                                                "throttle_floor"))
+        self.telemetry: Optional[MetricsRegistry] = None
+        self.tracer: Optional[Tracer] = tracer_for(self.cfg.telemetry)
         self.dur: Optional[EngineDurability] = None
         if self.cfg.durability is not None:
             self.dur = EngineDurability(self.cfg.durability, workflow,
                                         self.cfg.queue_capacity,
-                                        self.cfg.batch_size)
-        self.telemetry: Optional[MetricsRegistry] = None
-        self.tracer: Optional[Tracer] = tracer_for(self.cfg.telemetry)
+                                        self.cfg.batch_size,
+                                        tracer=self.tracer)
+        # what the last recover() restored and replayed
+        self.last_recovery: Optional[Dict[str, Any]] = None
         if self.cfg.telemetry is not None:
             self.telemetry = MetricsRegistry(
                 self.cfg.telemetry, batch_size=self.cfg.batch_size)
@@ -531,7 +534,7 @@ class Engine:
         end = source_offset + n_ticks
         eng_tick = int(jax.device_get(state["tick"])) if self.dur else 0
         # pipelined write path (DESIGN.md section 17): boundary work
-        # splits into a cheap *begin* at the boundary (snapshot copies,
+        # splits into a cheap *begin* at the boundary (dirty-row gathers,
         # WAL epoch fence) and a blocking *commit* resolved right after
         # the NEXT chunk is dispatched, so store writes and telemetry
         # transfers overlap device compute instead of serializing the
@@ -725,19 +728,18 @@ class Engine:
         t_recover = time.perf_counter()
         state = self.init_state()
         state["tick"] = jnp.asarray(f_tick, jnp.int32)
+        restored = {}
         with span(self.tracer, "recover_restore", frontier=f_tick):
             for up in self.wf.updaters():
-                recs = store.scan_records(
+                keys, ts, slates = store.scan_columns(
                     up.name, now=f_tick if up.ttl else None)
-                if not recs:
-                    continue
-                ks = np.asarray(sorted(recs), self.key_dtype)
-                ts = np.asarray([recs[int(k)][0] for k in ks], np.int32)
-                slates = jax.tree.map(
-                    lambda *rows: np.stack(rows),
-                    *[recs[int(k)][1] for k in ks])
-                state["tables"][up.name] = flush_mod.restore_into(
-                    state["tables"][up.name], ks, slates, ts)
+                restored[up.name] = int(keys.size)
+                if keys.size:
+                    state["tables"][up.name] = flush_mod.restore_into(
+                        state["tables"][up.name],
+                        keys.astype(self.key_dtype), slates,
+                        ts.astype(np.int32))
+        t_replay = time.perf_counter()
 
         # replay, preserving the per-tick batch structure (gaps in the
         # log — drain ticks, empty-source ticks — replay as empty ticks)
@@ -767,10 +769,15 @@ class Engine:
                     flush_pending()
             flush_pending()
             sp["replayed_ticks"] = replayed
+        t_end = time.perf_counter()
+        self.last_recovery = {"frontier": f_tick, "restored": restored,
+                              "restore_s": t_replay - t_recover,
+                              "replay_s": t_end - t_replay,
+                              "replayed_ticks": replayed}
         # the migration path measures pause_s around _reconfigure; the
         # crash path surfaces its restore+replay wall time the same way
         if self.telemetry is not None:
-            self.telemetry.note_recovery(time.perf_counter() - t_recover)
+            self.telemetry.note_recovery(t_end - t_recover)
         return state
 
     def close(self):
@@ -828,4 +835,5 @@ class Engine:
                               for k, t in state["tables"].items()},
             "table_claim_rounds": {k: int(g(t.claim_rounds))
                                    for k, t in state["tables"].items()},
+            **(self.dur.counters() if self.dur is not None else {}),
         }

@@ -71,6 +71,7 @@ class WriteAheadLog:
         self._trim_torn_tail()
         self._f = open(path, "ab")
         self._end = self._base + os.path.getsize(path) - self._hdr_len
+        self.bytes_written = 0      # appended by this handle
 
     # ---- offsets ----
     def _read_header(self) -> Tuple[int, int]:
@@ -124,6 +125,7 @@ class WriteAheadLog:
         if self.sync:
             os.fsync(self._f.fileno())
         self._end += 8 + len(raw)
+        self.bytes_written += 8 + len(raw)
         return self._end
 
     def close(self):

@@ -99,7 +99,10 @@ def _render_stats(doc: _Doc, stats: Dict[str, Any]):
                 "throttle_hits": "events shed at admission",
                 "deferred": "run tails re-queued by hotspot backpressure",
                 "shed_requests": "requests shed at admission",
-                "completed": "requests completed"}
+                "completed": "requests completed",
+                "store_bytes_written": "bytes of slate-store blocks flushed",
+                "store_bytes_compacted": "bytes of slate-store blocks merged",
+                "wal_bytes_written": "bytes appended to the write-ahead log"}
     if "tick" in stats:
         doc.add("tick", "gauge", "engine tick at last read",
                 stats["tick"])

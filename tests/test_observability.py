@@ -299,6 +299,50 @@ def test_engine_run_emits_phase_spans():
         eng.close()
 
 
+def test_durable_write_spans_and_counters(tmp_path):
+    """The flusher thread names each store write and the log's writer
+    each append; the engine's counters add up the rows flushed and the
+    bytes the store and the log were given."""
+    import os
+    from repro.core.durability import DurabilityConfig
+    from repro.slates.flush import FlushConfig, FlushPolicy
+    d = str(tmp_path / "d")
+    eng = Engine(_wf(), EngineConfig(
+        batch_size=32, queue_capacity=128, chunk_size=4,
+        telemetry=TelemetryConfig(window=4, trace=True),
+        durability=DurabilityConfig(
+            dir=d, flush=FlushConfig(policy=FlushPolicy.EVERY_K,
+                                     every_k=4))))
+    src = lambda t, _mx: {"S1": make_batch(
+        np.arange(8) + t, ts=np.full(8, t, np.int32))}
+    state, _ = eng.run(eng.init_state(), src, 12)
+    writes = eng.tracer.spans("store_write")
+    appends = eng.tracer.spans("wal_append")
+    assert len(writes) >= 2 and len(appends) == 12
+    assert sorted(a["args"]["tick"] for a in appends) == \
+        sorted({a["args"]["tick"] for a in appends})
+    main = {e["tid"] for e in eng.tracer.spans("chunk_dispatch")}
+    assert not main & {e["tid"] for e in writes + appends}
+    st = eng.stats(state)
+    assert st["flush_rows"] == {"U1": sum(w["args"]["rows"]
+                                          for w in writes)}
+    assert st["flush_rows"]["U1"] > 0
+    assert st["store_bytes_written"] == sum(w["args"]["bytes"]
+                                            for w in writes)
+    on_disk = sum(os.path.getsize(os.path.join(r, f))
+                  for r, _, fs in os.walk(os.path.join(d, "store"))
+                  for f in fs)
+    assert st["store_bytes_compacted"] == 0       # no size class is full
+    assert st["store_bytes_written"] == on_disk
+    assert st["wal_bytes_written"] == \
+        os.path.getsize(os.path.join(d, "wal.log")) - 12   # its header
+    from repro.telemetry.prom import render_prometheus
+    text = render_prometheus(stats=st)
+    assert (f"muppet_wal_bytes_written_total {st['wal_bytes_written']}"
+            in text)
+    eng.close()
+
+
 TICK_SCOPES = ("tick.queues", "tick.telemetry", "tick.map", "apply.sort",
                "apply.probe", "apply.pack", "apply.write")
 

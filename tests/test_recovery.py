@@ -129,6 +129,74 @@ def test_crash_recover_bitwise_parity(tmp_path, fused):
     assert_tables_bitwise_equal(base, rec)
 
 
+def test_halt_stops_every_thread_and_leaves_a_recoverable_directory(
+        tmp_path):
+    """``halt`` is a crash with the process still up: the log writer,
+    flusher and merge threads stop without a flush, and a fresh engine
+    recovers the halted one's slates from the directory while it stays
+    open; its ``close`` afterwards writes nothing."""
+    d = str(tmp_path / "h")
+    ea = _counting_engine(d, "jnp")
+    sa, _ = ea.run(ea.init_state(), counting_source, 14)
+    assert 0 < ea.dur.frontier.tick < int(jax.device_get(sa["tick"]))
+    base = table_dict(sa, "U1")
+    ea.dur.halt()
+    fl = ea.dur.flusher
+    assert not any(t.is_alive() for t in (ea.dur._wthread, fl._thread,
+                                          fl._merger))
+    files = sorted(os.path.relpath(os.path.join(p, f), d)
+                   for p, _, fs in os.walk(d) for f in fs)
+
+    eb = _counting_engine(d, "jnp")
+    sb = eb.recover()
+    assert eb.last_recovery["replayed_ticks"] > 0
+    assert_tables_bitwise_equal(base, table_dict(sb, "U1"))
+    eb.close()
+    ea.close()
+    assert sorted(os.path.relpath(os.path.join(p, f), d)
+                  for p, _, fs in os.walk(d) for f in fs) == files
+
+
+def test_crash_between_store_write_and_frontier_save_is_exactly_once(
+        tmp_path, monkeypatch):
+    """A crash after a flush's blocks reached the store and before its
+    frontier was saved: the rows of that flush must not be restored on
+    top of a replay that applies their events again."""
+    n_total = 24
+    ea = _counting_engine(str(tmp_path / "a"), "jnp")
+    sa, _ = ea.run(ea.init_state(), counting_source, n_total)
+    base = table_dict(sa, "U1")
+    ea.close()
+
+    d = str(tmp_path / "b")
+    eb = _counting_engine(d, "jnp")
+    saves = []
+    orig = FlushFrontier.save
+
+    def save(self, path):
+        saves.append(self.tick)
+        if len(saves) == 2:              # the second flush: blocks written
+            raise KeyboardInterrupt("crash before the frontier is saved")
+        orig(self, path)
+    monkeypatch.setattr(FlushFrontier, "save", save)
+    sb, _ = eb.run(eb.init_state(), counting_source, 8)
+    with pytest.raises(KeyboardInterrupt):
+        # the flush at source tick 16 fences the log, writes its blocks
+        # and crashes before its frontier is saved
+        eb.run(sb, counting_source, 8, source_offset=8)
+    eb.dur.halt()
+    monkeypatch.setattr(FlushFrontier, "save", orig)
+    assert eb.dur.store.last_seq > FlushFrontier.load(
+        os.path.join(d, "FRONTIER.json")).store_seq
+
+    ec = _counting_engine(d, "jnp")
+    sc = ec.recover()
+    sc, _ = ec.run(sc, counting_source, n_total - 16, source_offset=16)
+    assert_tables_bitwise_equal(base, table_dict(sc, "U1"))
+    ec.close()
+    eb.close()
+
+
 def test_recover_uses_store_not_only_wal(tmp_path):
     """After WAL truncation at the frontier, pre-frontier events exist
     only as flushed slates — recovery must come from the store."""
