@@ -190,6 +190,7 @@ def _apply_associative_fused(updater: AssociativeUpdater,
         base_vals = jax.tree.map(
             lambda tv: tv.at[safe_fresh].set(0, mode="drop"), table.vals)
 
+    stalls = 0                  # the kernel's window stalls (below)
     backend = impl
     if backend == "auto":
         backend = ("pallas" if jax.default_backend() == "tpu"
@@ -220,9 +221,8 @@ def _apply_associative_fused(updater: AssociativeUpdater,
         with jax.named_scope("apply.pack"):
             packed_deltas = packing.pack(deltas, spec)    # [B, D] aligned
             packed_vals = packing.pack(base_vals, spec)   # [C, D]
-        packed_vals = slate_ops.slate_update(key, packed_deltas, slots,
-                                             packed_vals, impl=backend,
-                                             op=op)
+        packed_vals, stalls = slate_ops.slate_update(
+            key, packed_deltas, slots, packed_vals, impl=backend, op=op)
         with jax.named_scope("apply.pack"):
             vals = packing.unpack(packed_vals, spec)
 
@@ -230,7 +230,9 @@ def _apply_associative_fused(updater: AssociativeUpdater,
         # bookkeeping scatter (ts / dirty), same slots write_slates hits
         ts = table.ts.at[safe].set(tick, mode="drop")
         dirty = table.dirty.at[safe].set(True, mode="drop")
-    table = replace(table, ts=ts, dirty=dirty, vals=vals)
+        window_stalls = table.window_stalls + stalls
+    table = replace(table, ts=ts, dirty=dirty, vals=vals,
+                    window_stalls=window_stalls)
     return table, {}, batch.count()
 
 

@@ -1799,8 +1799,8 @@ class DistributedEngine:
         migrators the caller runs next scan every old slice and rebuild
         at the new shard count.  Per-slot *lifetime* counters — the
         count-min sketch's counts/total/sample_n, ``processed``,
-        ``exchange_dropped``, ``throttle_hits``, the table/queue
-        ``dropped`` tallies and the tables' ``claim_rounds`` — are
+        ``exchange_dropped``, ``throttle_hits``, the queues' ``dropped``
+        and the tables' ``tbl.TALLIES`` — are
         folded from the dead slots into the first survivor before
         slicing, so ``TelemetryReport`` lifetime counts stay exact
         across a compaction (the sketch key-sample ring is positional,
@@ -1855,8 +1855,8 @@ class DistributedEngine:
                 out[key] = jax.tree.map(sel, val)
         # table/queue tallies stay at the old size for the host
         # migrators, which inherit ``dropped[slot_map[d]]`` (and the
-        # tables' ``claim_rounds``) — park the dead slots' counts on the
-        # first survivor so they carry
+        # tables' other ``tbl.TALLIES``) — park the dead slots' counts on
+        # the first survivor so they carry
         if dead.size:
             def park(leaf):
                 a = np.asarray(leaf).copy()
@@ -1866,8 +1866,7 @@ class DistributedEngine:
 
             for name, t in host["tables"].items():
                 out["tables"][name] = replace(
-                    t, dropped=park(t.dropped),
-                    claim_rounds=park(t.claim_rounds))
+                    t, **{f: park(getattr(t, f)) for f in tbl.TALLIES})
             for name, q in host["queues"].items():
                 out["queues"][name] = replace(q, dropped=park(q.dropped))
         tick = int(np.asarray(host["tick"]).max())
@@ -1913,8 +1912,7 @@ class DistributedEngine:
         The input table's leading dim may exceed ``self.n_shards``
         (slot compaction): all old slices are scanned, the rebuild is
         stacked at the new count, and ``slot_map[d]`` names the old
-        slot whose ``dropped`` and ``claim_rounds`` tallies new slot ``d``
-        inherits."""
+        slot whose ``tbl.TALLIES`` new slot ``d`` inherits."""
         moved: Dict[str, int] = {}
         n = self.n_shards
         for up in self.wf.updaters():
@@ -1926,8 +1924,11 @@ class DistributedEngine:
             old2new[smap] = np.arange(n)
             sh, slot = np.nonzero(keys != -1)
             moved[up.name] = 0
-            drop = np.array(t.dropped)
-            claims = np.array(t.claim_rounds)
+            tallies = {f: np.array(getattr(t, f)) for f in tbl.TALLIES}
+
+            def inherited(d):
+                return {f: int(v[smap[d]]) for f, v in tallies.items()}
+
             if len(sh) == 0:
                 if keys.shape[0] != n:
                     out = []
@@ -1936,11 +1937,8 @@ class DistributedEngine:
                                              up.slate_spec(),
                                              key_dtype=self.key_dtype)
                         out.append(jax.device_get(replace(
-                            loc,
-                            dropped=jnp.asarray(int(drop[smap[d]]),
-                                                jnp.int32),
-                            claim_rounds=jnp.asarray(
-                                int(claims[smap[d]]), jnp.int32))))
+                            loc, **{f: jnp.asarray(v, jnp.int32)
+                                    for f, v in inherited(d).items()})))
                     tables[up.name] = jax.tree.map(
                         lambda *xs: np.stack(xs), *out)
                 continue
@@ -1955,7 +1953,7 @@ class DistributedEngine:
             for d in range(n):
                 pick = np.nonzero(owner == d)[0]
                 loc = self._build_local_table(
-                    up, int(drop[smap[d]]), int(claims[smap[d]]),
+                    up, inherited(d),
                     rkeys[pick], ts[pick],
                     dirty[pick],
                     jax.tree.map(lambda v: v[pick], vals))
@@ -1964,13 +1962,13 @@ class DistributedEngine:
                 lambda *xs: np.stack(xs), *out)
         return moved
 
-    def _build_local_table(self, up, dropped0: int, claims0: int,
+    def _build_local_table(self, up, tallies0: Dict[str, int],
                            in_keys, in_ts, in_dirty, in_vals
                            ) -> tbl.SlateTable:
         """One shard's fresh table from migrated rows (dup keys folded
         with the updater's combine, clean rows stay clean); its
-        ``dropped`` and ``claim_rounds`` tallies continue from
-        ``dropped0`` and ``claims0``."""
+        ``tbl.TALLIES`` continue from ``tallies0`` (rows it cannot place
+        count as ``dropped``)."""
         combine = getattr(up, "combine", None)
         # fold duplicate keys (two-choice partials converging here)
         first: Dict[int, int] = {}
@@ -1999,8 +1997,7 @@ class DistributedEngine:
         local = replace(
             tbl.make_table(up.table_capacity, up.slate_spec(),
                            key_dtype=self.key_dtype),
-            claim_rounds=jnp.asarray(claims0, jnp.int32))
-        drops = 0
+            **{f: jnp.asarray(v, jnp.int32) for f, v in tallies0.items()})
         for i in range(0, len(in_keys), 256):
             k = jnp.asarray(in_keys[i:i + 256], self.key_dtype)
             valid = jnp.ones(k.shape, bool)
@@ -2016,9 +2013,7 @@ class DistributedEngine:
             safe = jnp.where(keep_clean, slot, local.capacity)
             local = replace(
                 local, dirty=local.dirty.at[safe].set(False, mode="drop"))
-            drops += int(jax.device_get((~placed).sum()))
-        return replace(local,
-                       dropped=jnp.asarray(dropped0 + drops, jnp.int32))
+        return local
 
     def _migrate_queues_host(self, queues,
                              slot_map=None) -> Dict[str, int]:

@@ -835,5 +835,7 @@ class Engine:
                               for k, t in state["tables"].items()},
             "table_claim_rounds": {k: int(g(t.claim_rounds))
                                    for k, t in state["tables"].items()},
+            "table_window_stalls": {k: int(g(t.window_stalls))
+                                    for k, t in state["tables"].items()},
             **(self.dur.counters() if self.dur is not None else {}),
         }

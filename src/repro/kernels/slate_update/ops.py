@@ -6,6 +6,10 @@
   - "interpret": Pallas body in interpreter mode (CPU-testable; raises
                  like "pallas")
   - "ref":       pure-jnp segment-sum oracle
+
+Every backend returns ``(table_vals, stalls)``: the kernel's count of
+live rows whose window read waited for an earlier same-window write
+(``kernel.py``), 0 for the oracle, which moves no windows.
 """
 from __future__ import annotations
 
@@ -43,4 +47,5 @@ def slate_update(keys_sorted, deltas, slots, table_vals, *,
                                interpret=(impl == "interpret"), op=op)
     if impl != "ref":
         raise ValueError(f"unknown slate_update impl {impl!r}")
-    return _ref.slate_update(keys_sorted, deltas, slots, table_vals, op=op)
+    return (_ref.slate_update(keys_sorted, deltas, slots, table_vals,
+                              op=op), jnp.int32(0))

@@ -27,6 +27,8 @@ from repro.core.hashing import hash_key
 EMPTY = jnp.int32(-1)
 PROBES = 8          # static probe budget per lookup
 INSERT_ROUNDS = 4   # bounded claim rounds for batch insert
+# the table's lifetime int32 [] counters, carried across every rebuild
+TALLIES = ("dropped", "claim_rounds", "window_stalls")
 
 
 @jax.tree_util.register_dataclass
@@ -38,6 +40,7 @@ class SlateTable:
     vals: Any              # pytree, leaves [C, ...]
     dropped: jnp.ndarray   # int32 [] lifetime insert-failure count
     claim_rounds: jnp.ndarray  # int32 [] lifetime claim rounds run
+    window_stalls: jnp.ndarray  # int32 [] lifetime slate_update stalls
 
     @property
     def capacity(self) -> int:
@@ -60,6 +63,7 @@ def make_table(capacity: int, value_spec: Dict[str, Any],
         vals=vals,
         dropped=jnp.zeros((), jnp.int32),
         claim_rounds=jnp.zeros((), jnp.int32),
+        window_stalls=jnp.zeros((), jnp.int32),
     )
 
 

@@ -838,8 +838,9 @@ def test_planned_leave_with_backlog_stays_on_device_path():
 
 def test_compaction_folds_lifetime_counters():
     """_compact_physical slices dead slots away; their lifetime
-    counters (processed, drop tallies, count-min sketch mass) must fold
-    into survivors so TelemetryReport lifetime counts stay exact."""
+    counters (processed, drop tallies, count-min sketch mass, the
+    tables' window stalls and claim rounds) must fold into survivors so
+    TelemetryReport lifetime counts stay exact."""
     out = run_sub("""
         from repro.telemetry.metrics import TelemetryConfig
 
@@ -853,10 +854,11 @@ def test_compaction_folds_lifetime_counters():
             out['sk_total'] = int(np.asarray(sk['total']).sum())
             out['sk_counts'] = int(np.asarray(sk['counts']).sum())
             out['sk_sample_n'] = int(np.asarray(sk['sample_n']).sum())
-            tdrop = jax.device_get({k: t.dropped for k, t in
-                                    state['tables'].items()})
-            out['table_dropped'] = sum(int(np.asarray(v).sum())
-                                       for v in tdrop.values())
+            for f in ('dropped', 'window_stalls', 'claim_rounds'):
+                tally = jax.device_get({k: getattr(t, f) for k, t in
+                                        state['tables'].items()})
+                out['table_' + f] = sum(int(np.asarray(v).sum())
+                                        for v in tally.values())
             return out
 
         mesh = Mesh(np.array(jax.devices()[:4]), ('data',))
@@ -874,14 +876,25 @@ def test_compaction_folds_lifetime_counters():
             xs = rng.integers(0, 99, 64).astype(np.float32)
             state, _ = eng.step(state, {'S1': gb(keys, xs, t, 4)})
         state, _ = eng.drain(state, max_ticks=64)
+        # the generic path runs no kernel: give each shard's table its
+        # own stall tally, to be parked and resharded like the others
+        from dataclasses import replace
+        t = state['tables']['U1']
+        state['tables']['U1'] = replace(t, window_stalls=jax.device_put(
+            jnp.arange(1, 5, dtype=jnp.int32), t.window_stalls.sharding))
         before = lifetime(eng, state)
         assert (before['queue_dropped'] > 0 or
                 before['exchange_dropped'] > 0), before
+        assert before['table_window_stalls'] == 10
 
         state, rep = eng.remove_shards(state, [2, 3])
         state, rep = eng.compact(state)
         assert rep.recompiled and eng.n_shards == 2, rep
         after = lifetime(eng, state)
+        # the rebuilt tables' own inserts run claim rounds: that tally
+        # carries and grows
+        assert after.pop('table_claim_rounds') >= before.pop(
+            'table_claim_rounds') > 0
         for k in before:
             assert before[k] == after[k], (k, before[k], after[k])
         r = eng.telemetry.observe(eng, state)

@@ -80,12 +80,12 @@ def test_kernel_interpret_matches_ref_oracle():
     run_last = np.concatenate([keys[1:] != keys[:-1], [True]])
     slots = np.where(run_last, (keys * 11 + 5) % C, -1).astype(np.int32)
     table = rng.normal(size=(C, D)).astype(np.float32)
-    a = ops.slate_update(jnp.asarray(keys), jnp.asarray(deltas),
-                         jnp.asarray(slots), jnp.asarray(table),
-                         impl="interpret")
-    b = ops.slate_update(jnp.asarray(keys), jnp.asarray(deltas),
-                         jnp.asarray(slots), jnp.asarray(table),
-                         impl="ref")
+    a, _ = ops.slate_update(jnp.asarray(keys), jnp.asarray(deltas),
+                            jnp.asarray(slots), jnp.asarray(table),
+                            impl="interpret")
+    b, _ = ops.slate_update(jnp.asarray(keys), jnp.asarray(deltas),
+                            jnp.asarray(slots), jnp.asarray(table),
+                            impl="ref")
     assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-4
 
 
@@ -106,9 +106,149 @@ def test_kernel_multi_tile_runs_exact(op):
     table = rng.integers(0, 9, size=(C, D)).astype(np.float32)
     args = (jnp.asarray(keys), jnp.asarray(deltas), jnp.asarray(slots),
             jnp.asarray(table))
-    a = ops.slate_update(*args, impl="interpret", op=op)
-    b = ops.slate_update(*args, impl="ref", op=op)
+    a, _ = ops.slate_update(*args, impl="interpret", op=op)
+    b, _ = ops.slate_update(*args, impl="ref", op=op)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _model_stalls(slots):
+    """The hazard rule, counted the plain way: per kernel tile, a live
+    row (slot >= 0) stalls when its 128-lane window is that of one of
+    the RING - 1 live rows before it in the tile."""
+    from repro.kernels.slate_update import kernel
+    n = 0
+    for lo in range(0, len(slots), kernel.TILE_B):
+        wins = [s // kernel.LANES for s in slots[lo:lo + kernel.TILE_B]
+                if s >= 0]
+        n += sum(w in wins[max(0, m - kernel.RING + 1):m]
+                 for m, w in enumerate(wins))
+    return n
+
+
+def _live_batch(rng, live_slots, run_len=3):
+    """Sorted keys whose run-last rows carry ``live_slots`` in row order
+    (runs of 1..run_len rows), integer deltas (every combine order is
+    exact) and ``slots`` -1 on the other rows."""
+    runs = rng.integers(1, run_len + 1, len(live_slots))
+    keys = np.repeat(np.arange(len(live_slots), dtype=np.int32), runs)
+    slots = np.full(keys.size, -1, np.int32)
+    slots[np.cumsum(runs) - 1] = live_slots
+    return keys, slots
+
+
+def _window_case(case, rng):
+    """``(keys, slots, capacity, stalls)`` of one constructed batch; the
+    stall count is counted by hand (None: left to ``_model_stalls``)."""
+    from repro.kernels.slate_update import kernel
+    K, L = kernel.RING, kernel.LANES
+    if case == "one_window":          # every live row in window 0
+        keys, slots = _live_batch(rng, rng.permutation(L)[:100])
+        return keys, slots, 1000, 99
+    if case == "lane_edges":          # lanes 127 | 0 of neighbouring windows
+        live = [L - 1] + [s for w in range(1, 21) for s in (w * L,
+                                                            w * L + L - 1)]
+        keys, slots = _live_batch(rng, np.asarray(live))
+        return keys, slots, 21 * L + 5, 20
+    if case.startswith("ring_"):      # same-window rows d live rows apart
+        d = {"ring_1": 1, "ring_K-1": K - 1, "ring_K": K,
+             "ring_K+1": K + 1}[case]
+        wins = np.arange(4 * K + 2)
+        lanes = rng.integers(0, L - 1, wins.size)
+        for j in (0, K + 1, 2 * K + 2):       # three pairs, one at row 0
+            wins[j + d], lanes[j + d] = wins[j], lanes[j] + 1
+        keys, slots = _live_batch(rng, wins * L + lanes)
+        return keys, slots, wins.size * L, 3 if d < K else 0
+    if case == "tile_edge":
+        # runs of 2 up to row 999 (live rows 1, 3, .. 999), one run over
+        # rows 1000..1100 across the tile edge at 1024, then runs of 1;
+        # rows 999 (tile 0), 1100 and 1101 (tile 1) share a window, so
+        # only row 1101 stalls: the hazard never crosses tiles
+        B = 1400
+        keys = np.concatenate([np.arange(1000) // 2,
+                               np.full(101, 500),
+                               501 + np.arange(B - 1101)]).astype(np.int32)
+        last = np.concatenate([keys[1:] != keys[:-1], [True]])
+        slots = np.full(B, -1, np.int32)
+        live = np.nonzero(last)[0]
+        slots[live] = (np.arange(live.size) + 1) * L + rng.integers(
+            0, L, live.size)
+        slots[999], slots[1100], slots[1101] = 5, 77, 126
+        return keys, slots, (live.size + 1) * L, 1
+    if case == "no_live":
+        keys = np.sort(rng.integers(0, 50, 300)).astype(np.int32)
+        return keys, np.full(300, -1, np.int32), 256, 0
+    if case == "ragged_B":            # 1500 rows: two tiles, one padded
+        keys = np.sort(zipf_keys(rng, 1500, n_keys=600)).astype(np.int32)
+        last = np.concatenate([keys[1:] != keys[:-1], [True]])
+        perm = rng.permutation(2048)
+        slots = np.where(last, perm[keys], -1).astype(np.int32)
+        return keys, slots, 2048, None
+    raise ValueError(case)
+
+
+WINDOW_CASES = ["one_window", "lane_edges", "ring_1", "ring_K-1",
+                "ring_K", "ring_K+1", "tile_edge", "no_live", "ragged_B"]
+
+
+@pytest.mark.parametrize("op", ["sum", "max"])
+@pytest.mark.parametrize("case", WINDOW_CASES)
+def test_kernel_window_cases_exact(case, op):
+    """Batches aimed at the pipelined read-modify-writes: the kernel
+    (interpret) equals the oracle bit for bit, and counts exactly the
+    rows the hazard rule holds back — by hand and by the plain model."""
+    from repro.kernels.slate_update import ops
+    rng = np.random.default_rng(WINDOW_CASES.index(case))
+    keys, slots, C, want = _window_case(case, rng)
+    deltas = rng.integers(0, 5, size=(keys.size, 8)).astype(np.float32)
+    table = rng.integers(0, 9, size=(C, 8)).astype(np.float32)
+    args = (jnp.asarray(keys), jnp.asarray(deltas), jnp.asarray(slots),
+            jnp.asarray(table))
+    a, stalls = ops.slate_update(*args, impl="interpret", op=op)
+    b, none = ops.slate_update(*args, impl="ref", op=op)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert int(none) == 0
+    assert int(stalls) == _model_stalls(slots.tolist())
+    if want is not None:
+        assert int(stalls) == want
+
+
+@pytest.mark.parametrize("op", ["sum", "max"])
+def test_kernel_wide_keys_exact(op):
+    """int64 keys whose low 32 bits collide reach the kernel as
+    ``_segment_ids``: the same runs, bit for bit the oracle's table."""
+    from repro.kernels.slate_update import ops
+    rng = np.random.default_rng(11)
+    with jax.enable_x64(True):
+        base = np.sort(rng.integers(0, 40, 200))
+        keys = np.where(base % 3 == 0, base, base + (1 << 32)).astype(
+            np.int64)
+        keys = np.sort(keys)
+        last = np.concatenate([keys[1:] != keys[:-1], [True]])
+        slots = np.where(last, (base * 13 + 1) % 512, -1).astype(np.int32)
+        deltas = rng.integers(0, 5, size=(200, 8)).astype(np.float32)
+        table = rng.integers(0, 9, size=(512, 8)).astype(np.float32)
+        args = (jnp.asarray(keys), jnp.asarray(deltas), jnp.asarray(slots),
+                jnp.asarray(table))
+        assert args[0].dtype == jnp.int64
+        a, _ = ops.slate_update(*args, impl="interpret", op=op)
+        b, _ = ops.slate_update(*args, impl="ref", op=op)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("impl", ["interpret", "jnp", "ref"])
+def test_table_window_stalls_count(impl):
+    """``SlateTable.window_stalls`` adds the kernel's count: ten keys in
+    a 128-slot table all share its one window, so each fused update of
+    them holds back every live row after the first (9); the backends
+    that move no windows leave it at 0."""
+    up = FusedCountingUpdater()
+    table = tbl.make_table(128, up.slate_spec())
+    batch = make_batch(np.repeat(np.arange(10) * 11, 3))
+    for tick in range(2):
+        table, _, _ = apply_mod.apply_associative(up, table, batch,
+                                                  tick=tick, impl=impl)
+    assert int(table.dropped) == 0
+    assert int(table.window_stalls) == (18 if impl == "interpret" else 0)
 
 
 def test_unsupported_width_falls_back_to_ref():
@@ -129,8 +269,8 @@ def test_unsupported_width_falls_back_to_ref():
     for impl in ("pallas", "interpret"):
         with pytest.raises(ValueError, match="D % 8"):
             ops.slate_update(*args, impl=impl)
-    ref = ops.slate_update(*args, impl="ref")
-    assert ref.shape == (C, D)
+    ref, stalls = ops.slate_update(*args, impl="ref")
+    assert ref.shape == (C, D) and int(stalls) == 0
 
 
 def test_pack_unpack_roundtrip():

@@ -95,8 +95,8 @@ def test_slate_update_sweep(B, D, C, n_keys):
     run_last = np.concatenate([keys[1:] != keys[:-1], [True]])
     slots = np.where(run_last, (keys * 7 + 3) % C, -1).astype(np.int32)
     table = rng.normal(size=(C, D)).astype(np.float32)
-    a = ker(jnp.asarray(keys), jnp.asarray(deltas), jnp.asarray(slots),
-            jnp.asarray(table), interpret=True)
+    a, _ = ker(jnp.asarray(keys), jnp.asarray(deltas), jnp.asarray(slots),
+               jnp.asarray(table), interpret=True)
     b = ref(jnp.asarray(keys), jnp.asarray(deltas), jnp.asarray(slots),
             jnp.asarray(table))
     err = np.abs(np.asarray(a) - np.asarray(b)).max()

@@ -148,10 +148,10 @@ def test_slate_update_max_kernel_matches_ref():
     last[:-1] = keys[:-1] != keys[1:]
     slots = np.where(last, keys % N, -1).astype(np.int32)
     rows = np.abs(rng.normal(size=(N, C))).astype(np.float32)
-    out_ref = su_ops.slate_update(
+    out_ref, _ = su_ops.slate_update(
         jnp.asarray(keys), jnp.asarray(deltas), jnp.asarray(slots),
         jnp.asarray(rows), impl="ref", op="max")
-    out_int = su_ops.slate_update(
+    out_int, _ = su_ops.slate_update(
         jnp.asarray(keys), jnp.asarray(deltas), jnp.asarray(slots),
         jnp.asarray(rows), impl="interpret", op="max")
     np.testing.assert_array_equal(np.asarray(out_ref),

@@ -347,7 +347,7 @@ TICK_SCOPES = ("tick.queues", "tick.telemetry", "tick.map", "apply.sort",
                "apply.probe", "apply.pack", "apply.write")
 
 
-def _counting_app(capacity=256, batch=32):
+def _counting_app(capacity=256, batch=32, fused="ref"):
     """Paper Examples 1/4 at a tiny size: a mapper, a counter and an
     [8]-lane sum, both on the packed path."""
     from repro import App, EventBatch, RuntimeConfig, ops
@@ -367,7 +367,7 @@ def _counting_app(capacity=256, batch=32):
     def lanes(b):
         return {"v": jnp.ones((b.key.shape[0], 8), jnp.float32)}
 
-    app.start(RuntimeConfig(batch_size=batch, chunk_size=2, fused="ref",
+    app.start(RuntimeConfig(batch_size=batch, chunk_size=2, fused=fused,
                             telemetry=TelemetryConfig(impl="jnp")))
 
     def source(t, _mx=None):
@@ -416,6 +416,21 @@ def test_claim_rounds_run_only_for_missing_keys():
     grown = eng.stats(state)["table_claim_rounds"]
     assert all(grown[k] > loaded[k] for k in loaded)
     assert sum(eng.stats(state)["table_dropped"].values()) == 0
+
+
+def test_app_stats_report_window_stalls():
+    """``App.stats()`` shows each table's ``table_window_stalls`` beside
+    its ``table_claim_rounds``.  The tables have one 128-lane window, so
+    each kernel update of a tick's 8 keys holds back 7 of its 8 rows."""
+    app, source = _counting_app(capacity=128, batch=8, fused="interpret")
+    app.run(lambda t, _mx=None: source(0), 4)
+    st = app.stats()
+    assert set(st["table_window_stalls"]) == set(st["table_claim_rounds"])
+    assert set(st["table_window_stalls"]) == {"U1", "UV"}
+    assert sum(st["table_dropped"].values()) == 0
+    for name, stalls in st["table_window_stalls"].items():
+        assert stalls == 7 * st["processed"][name] // 8 > 0
+    app.close()
 
 
 def test_engine_run_spans_reach_the_profiler(tmp_path):
