@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import threading
 import time
-from collections import deque
 from dataclasses import dataclass, field, replace
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
@@ -39,16 +38,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import apply as apply_mod
 from repro.core import queues as q_mod
+from repro.core import tick as tick_mod
 from repro.core.durability import (DurabilityConfig, EngineDurability,
                                    merge_replay_ticks)
-from repro.core.engine import EngineConfig, resolve_key_dtype
+from repro.core.engine import Engine, EngineConfig, resolve_key_dtype
 from repro.core.event import EventBatch, concat
 from repro.core.hashing import HashRing, route, route_secondary
-from repro.core.operators import (AssociativeUpdater, Mapper,
-                                  SequentialUpdater, Updater)
-from repro.core.queues import OverflowPolicy
+from repro.core.operators import AssociativeUpdater
 from repro.core.workflow import Workflow
 from repro.slates import flush as flush_mod
 from repro.slates import table as tbl
@@ -409,9 +406,6 @@ class DistributedEngine:
         cap = int(self.cfg.batch_size * self.cfg.exchange_slack
                   / self.n_shards)
         self.cap_per_dest = max(8, cap)
-        self._step = None
-        self._chunk = None
-        self._empty_step = None
         self._plan_fn = None       # device-migration owner-count jit
         self._migrate_fns = {}     # (row_cap, ev_cap) -> jitted exchange
         self._read_fns = {}        # (updater, with_sec) -> batched read
@@ -434,6 +428,7 @@ class DistributedEngine:
             tele = self.cfg.autoscale.telemetry or TelemetryConfig()
         self.tele_cfg = tele
         self.telemetry: Optional[MetricsRegistry] = None
+        self._salts = None
         self.tracer: Optional[Tracer] = tracer_for(tele)
         self.dur: Optional[EngineDurability] = None
         if self.cfg.durability is not None:
@@ -460,6 +455,7 @@ class DistributedEngine:
         self._hot_keys = np.zeros(max(1, self._hot_capacity),
                                   self.key_dtype)
         self._hot_valid = np.zeros(max(1, self._hot_capacity), bool)
+        self._build_tick_programs()
 
     @property
     def key_bits(self) -> int:
@@ -521,132 +517,36 @@ class DistributedEngine:
     # ---- the per-shard tick ----
     def _local_tick(self, state, sources, ring_hashes, ring_shards,
                     hot_keys, hot_valid):
-        cfg, wf = self.cfg, self.wf
-        queues = {k: jax.tree.map(lambda x: x[0], v)
-                  for k, v in state["queues"].items()}
-        tables = {k: jax.tree.map(lambda x: x[0], v)
-                  for k, v in state["tables"].items()}
-        processed = {k: v[0] for k, v in state["processed"].items()}
-        exchange_dropped = state["exchange_dropped"][0]
-        throttle_hits = state["throttle_hits"][0]
-        deferred_total = state["deferred"][0]
-        tick = state["tick"][0]
-        sketch = None
-        if "sketch" in state:
-            sketch = {k: v[0] for k, v in state["sketch"].items()}
-        lat_hist = None
-        if "lat_hist" in state:
-            lat_hist = {k: jax.tree.map(lambda x: x[0], v)
-                        for k, v in state["lat_hist"].items()}
-        sources = {k: jax.tree.map(lambda x: x[0], v)
-                   for k, v in sources.items()}
-        outputs: Dict[str, List[EventBatch]] = {}
+        """One shard's tick under ``shard_map``: the shared tick
+        (``core/tick.py``) on this shard's rows, with the ring route and
+        the ``all_to_all`` exchange as its ``send``."""
+        local = jax.tree.map(lambda x: x[0], state)
+        tick = local["tick"]
+        exchange_dropped = local.pop("exchange_dropped")
 
-        def deliver_all(items):
-            nonlocal throttle_hits, exchange_dropped
-            work = deque(items)
-            for _ in range(len(work) + 64):
-                if not work:
-                    return
-                stream, batch = work.popleft()
-                subs = wf.dests_of(stream)
-                if not subs:
-                    outputs.setdefault(stream, []).append(batch)
-                    continue
-                for dest_op in subs:
-                    op = wf.by_name[dest_op]
-                    dshard = route(batch.key, _salt(dest_op), ring_hashes,
-                                   ring_shards)
-                    if (cfg.two_choice_threshold
-                            and isinstance(op, AssociativeUpdater)):
-                        dshard = self._two_choice(batch, dshard, dest_op,
-                                                  ring_hashes, ring_shards)
-                    elif (self._hot_capacity
-                            and isinstance(op, AssociativeUpdater)):
-                        dshard = self._hot_split(
-                            batch, dshard, dest_op, ring_hashes,
-                            ring_shards, hot_keys, hot_valid, tick)
-                    recv, dropped = exchange(batch, dshard, self.axes,
-                                             self.cap_per_dest)
-                    exchange_dropped = exchange_dropped + dropped
-                    nq, ovf = q_mod.enqueue(queues[dest_op], recv)
-                    pol = cfg.policy_for(dest_op)
-                    if pol is OverflowPolicy.DROP:
-                        nq = q_mod.count_drop(nq, ovf)
-                    elif pol is OverflowPolicy.OVERFLOW_STREAM:
-                        work.append((cfg.overflow_stream[dest_op], ovf))
-                    elif pol is OverflowPolicy.THROTTLE:
-                        throttle_hits = throttle_hits + ovf.count()
-                        nq = q_mod.count_drop(nq, ovf)
-                    queues[dest_op] = nq
-            raise RuntimeError("overflow-stream routing did not converge")
+        def send(dest_op, batch):
+            nonlocal exchange_dropped
+            op = self.wf.by_name[dest_op]
+            dshard = route(batch.key, _salt(dest_op), ring_hashes,
+                           ring_shards)
+            if (self.cfg.two_choice_threshold
+                    and isinstance(op, AssociativeUpdater)):
+                dshard = self._two_choice(batch, dshard, dest_op,
+                                          ring_hashes, ring_shards)
+            elif self._hot_capacity and isinstance(op, AssociativeUpdater):
+                dshard = self._hot_split(batch, dshard, dest_op,
+                                         ring_hashes, ring_shards,
+                                         hot_keys, hot_valid, tick)
+            recv, n = exchange(batch, dshard, self.axes, self.cap_per_dest)
+            exchange_dropped = exchange_dropped + n
+            return recv
 
-        deliver_all(list(sources.items()))
-        emitted_now: List[Tuple[str, EventBatch]] = []
-
-        for op in wf.operators:
-            queues[op.name], batch = q_mod.dequeue(queues[op.name],
-                                                   cfg.batch_size)
-            if sketch is not None and isinstance(op, Updater):
-                # per-shard key heat from the *routed* keys this shard's
-                # updaters dequeue — the per-arc signal rebalance wants.
-                # Pure extra state; the tick never reads it (parity).
-                sketch = sk_mod.sketch_update(
-                    sketch, batch.key, batch.valid, self._salts,
-                    impl=self.tele_cfg.impl)
-            if lat_hist is not None and isinstance(op, Updater):
-                # per-shard event age at dequeue (DESIGN.md 18): for a
-                # terminal updater this is end-to-end event-time-to-
-                # slate-visibility — same parity contract as the sketch
-                lat_hist[op.name] = lat_mod.hist_update(
-                    lat_hist[op.name], tick, batch.ts, batch.valid,
-                    n_buckets=self.tele_cfg.latency_buckets,
-                    impl=self.tele_cfg.impl)
-            if isinstance(op, Mapper):
-                outs = op.map_batch(batch)
-                for s, b in outs.items():
-                    emitted_now.append((s, b.mask(batch.valid & b.valid)))
-                processed[op.name] = processed[op.name] + batch.count()
-            elif isinstance(op, AssociativeUpdater):
-                tables[op.name], ems, n = apply_mod.apply_associative(
-                    op, tables[op.name], batch, tick, impl=cfg.fused)
-                emitted_now.extend(ems.items())
-                processed[op.name] = processed[op.name] + n
-            elif isinstance(op, SequentialUpdater):
-                tables[op.name], ems, deferred, n = \
-                    apply_mod.apply_sequential(op, tables[op.name], batch,
-                                               tick)
-                emitted_now.extend(ems.items())
-                deferred_total = deferred_total + deferred.count()
-                nq, ovf = q_mod.enqueue(queues[op.name], deferred)
-                queues[op.name] = q_mod.count_drop(nq, ovf)
-                processed[op.name] = processed[op.name] + n
-
-        for up in wf.updaters():
-            if up.ttl:
-                tables[up.name] = tbl.expire_ttl(tables[up.name], tick,
-                                                 up.ttl)
-
-        deliver_all(emitted_now)
-
-        out_batches = {s: concat(bs) if len(bs) > 1 else bs[0]
-                       for s, bs in outputs.items()}
+        new, outs = tick_mod.tick(
+            self.wf, self.cfg, self.tele_cfg, self._salts, local,
+            jax.tree.map(lambda x: x[0], sources), send)
+        new["exchange_dropped"] = exchange_dropped
         lift = lambda t: jax.tree.map(lambda x: x[None], t)
-        new_state = {
-            "queues": {k: lift(v) for k, v in queues.items()},
-            "tables": {k: lift(v) for k, v in tables.items()},
-            "tick": (tick + 1)[None],
-            "exchange_dropped": exchange_dropped[None],
-            "throttle_hits": throttle_hits[None],
-            "deferred": deferred_total[None],
-            "processed": {k: v[None] for k, v in processed.items()},
-        }
-        if sketch is not None:
-            new_state["sketch"] = {k: v[None] for k, v in sketch.items()}
-        if lat_hist is not None:
-            new_state["lat_hist"] = {k: lift(v)
-                                     for k, v in lat_hist.items()}
-        return new_state, {k: lift(v) for k, v in out_batches.items()}
+        return lift(new), lift(outs)
 
     def _two_choice(self, batch, primary, dest_op, ring_hashes,
                     ring_shards):
@@ -681,10 +581,12 @@ class DistributedEngine:
                 & 1) == 1
         return jnp.where(is_hot & flip & batch.valid, secondary, primary)
 
-    def _hot_table(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """The hot-key split set as runtime tick inputs (ring-style:
-        contents swap, shape never does)."""
-        return jnp.asarray(self._hot_keys), jnp.asarray(self._hot_valid)
+    def _tick_inputs(self):
+        """The ring and the hot-key split set as runtime tick inputs:
+        contents swap, shapes never do, so nothing recompiles."""
+        rh, rs = self.ring.table()
+        return (rh, rs, jnp.asarray(self._hot_keys),
+                jnp.asarray(self._hot_valid))
 
     # ---- jit plumbing ----
     def _spec_like(self, tree):
@@ -695,25 +597,44 @@ class DistributedEngine:
             if (hasattr(x, "ndim") and x.ndim >= 1
                 and x.shape[0] == self.n_shards) else rep, tree)
 
+    def _shard_jit(self, local_fn, src_spec, out_specs):
+        """``jit(shard_map(local_fn))`` over ``(state, sources, *tick
+        inputs)``, donating the state.  The specs are taken from the
+        traced arguments, so a new source structure (``{}`` for a drain
+        tick) traces its own program; ``out_specs(state_specs)`` gives
+        the output specs."""
+        rep = P()
+
+        def run(st, src, rh, rs, hk, hv):
+            specs = self._spec_like(st)
+            fn = jax.shard_map(
+                local_fn, mesh=self.mesh,
+                in_specs=(specs, jax.tree.map(lambda _: src_spec, src),
+                          rep, rep, rep, rep),
+                out_specs=out_specs(specs), check_vma=False)
+            return fn(st, src, rh, rs, hk, hv)
+
+        return jax.jit(run, donate_argnums=(0,))
+
+    def _local_chunk(self, st, src, rh, rs, hk, hv):
+        def body(s, x):
+            s2, outs = self._local_tick(s, x, rh, rs, hk, hv)
+            return s2, (outs, s2["throttle_hits"])
+        final, (outs, hits) = jax.lax.scan(body, st, src)
+        return final, outs, hits
+
+    def _build_tick_programs(self):
+        sharded, stacked = P(self.axes), P(None, self.axes)
+        self._step = self._shard_jit(self._local_tick, sharded,
+                                     lambda specs: sharded)
+        self._chunk = self._shard_jit(
+            self._local_chunk, stacked,
+            lambda specs: (specs, stacked, stacked))
+
     def step(self, state, sources: Dict[str, EventBatch]):
         """sources: global batches with leading dim n_shards*B_loc or
         [n_shards, B_loc] — pass [n_shards, B_loc] (leading shard axis)."""
-        if self._step is None:
-            sharded, rep = P(self.axes), P()
-            state_specs = self._spec_like(state)
-            src_specs = jax.tree.map(lambda _: sharded, sources)
-
-            def run(st, src, rh, rs, hk, hv):
-                fn = jax.shard_map(
-                    self._local_tick, mesh=self.mesh,
-                    in_specs=(state_specs, src_specs, rep, rep, rep, rep),
-                    out_specs=sharded, check_vma=False)
-                return fn(st, src, rh, rs, hk, hv)
-
-            self._step = jax.jit(run, donate_argnums=(0,))
-        rh, rs = self.ring.table()
-        hk, hv = self._hot_table()
-        return self._step(state, sources, rh, rs, hk, hv)
+        return self._step(state, sources, *self._tick_inputs())
 
     def run_chunk(self, state, stacked_sources: Dict[str, EventBatch]):
         """T device-resident ticks in one dispatch (DESIGN.md 2.2).
@@ -725,31 +646,8 @@ class DistributedEngine:
         [T, n_shards] on-device per-tick trace, so the host syncs once
         per chunk for the backpressure signal.
         """
-        if self._chunk is None:
-            stacked = P(None, self.axes)
-            rep = P()
-            state_specs = self._spec_like(state)
-            src_specs = jax.tree.map(lambda _: stacked, stacked_sources)
-
-            def local_chunk(st, src, rh, rs, hk, hv):
-                def body(s, x):
-                    s2, outs = self._local_tick(s, x, rh, rs, hk, hv)
-                    return s2, (outs, s2["throttle_hits"])
-                final, (outs, hits) = jax.lax.scan(body, st, src)
-                return final, outs, hits
-
-            def run(st, src, rh, rs, hk, hv):
-                fn = jax.shard_map(
-                    local_chunk, mesh=self.mesh,
-                    in_specs=(state_specs, src_specs, rep, rep, rep, rep),
-                    out_specs=(state_specs, stacked, stacked), check_vma=False)
-                return fn(st, src, rh, rs, hk, hv)
-
-            self._chunk = jax.jit(run, donate_argnums=(0,))
-        rh, rs = self.ring.table()
-        hk, hv = self._hot_table()
-        state, outs, hits = self._chunk(state, stacked_sources, rh, rs,
-                                        hk, hv)
+        state, outs, hits = self._chunk(state, stacked_sources,
+                                        *self._tick_inputs())
         return state, outs, {"throttle_hits": hits}
 
     # ---- durability (DESIGN.md section 10): per-shard WAL + frontier --
@@ -797,35 +695,11 @@ class DistributedEngine:
 
     def _step_empty(self, state):
         """One source-less tick (drain barriers, replay gap ticks)."""
-        if self._empty_step is None:
-            sharded, rep = P(self.axes), P()
-            state_specs = self._spec_like(state)
+        return self.step(state, {})[0]
 
-            def run(st, rh, rs, hk, hv):
-                fn = jax.shard_map(
-                    lambda s, h, r, k, v: self._local_tick(s, {}, h, r,
-                                                           k, v),
-                    mesh=self.mesh,
-                    in_specs=(state_specs, rep, rep, rep, rep),
-                    out_specs=sharded, check_vma=False)
-                return fn(st, rh, rs, hk, hv)
-
-            self._empty_step = jax.jit(run, donate_argnums=(0,))
-        rh, rs = self.ring.table()
-        hk, hv = self._hot_table()
-        state, _ = self._empty_step(state, rh, rs, hk, hv)
-        return state
-
-    def _drain_queues(self, state, max_ticks: int):
-        d = 0
-        while d < max_ticks:
-            sizes = jax.device_get({k: q.size
-                                    for k, q in state["queues"].items()})
-            if all(int(v.sum()) == 0 for v in sizes.values()):
-                break
-            state = self._step_empty(state)
-            d += 1
-        return state, d
+    # one drain barrier for both engines: queue sizes sum over shards
+    _drain_queues = Engine._drain_queues
+    drain = Engine.drain
 
     def _flush_boundary(self, state, tick: int, meta=None):
         """Barrier-drain, flush every shard's dirty slates (one
@@ -1056,11 +930,6 @@ class DistributedEngine:
                     handle.state = state
         self.tick_cursor = src_t
         return state, outputs
-
-    def drain(self, state, max_ticks: int = 64):
-        """Run source-less ticks until every shard's queues are empty
-        (or ``max_ticks``).  Returns ``(state, ticks_run)``."""
-        return self._drain_queues(state, max_ticks)
 
     def run_durable(self, state, source_fn, n_ticks: int, *,
                     start_tick: int = 0):
@@ -1781,7 +1650,7 @@ class DistributedEngine:
         cap = int(self.cfg.batch_size * self.cfg.exchange_slack
                   / self.n_shards)
         self.cap_per_dest = max(8, cap)
-        self._step = self._chunk = self._empty_step = None
+        self._build_tick_programs()
         self._plan_fn = None
         self._migrate_fns = {}
         self._read_fns = {}
@@ -2220,10 +2089,9 @@ class DistributedEngine:
                 fn = self._make_read_fn(state["tables"][updater],
                                         updater, with_sec, impl)
                 self._read_fns[cache_key] = fn
-            rh, rs = self.ring.table()
-            hk, hv = self._hot_table()
             res = jax.device_get(fn(state["tables"][updater],
-                                    jnp.asarray(keys_np), rh, rs, hk, hv))
+                                    jnp.asarray(keys_np),
+                                    *self._tick_inputs()))
         # host select over the gathered partials: at most one shard's
         # mask bit is set per (key, role), so argmax IS the owner
         mask, rows = np.asarray(res[0]), res[1]

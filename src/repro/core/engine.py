@@ -14,30 +14,29 @@ Two dispatch granularities (DESIGN.md section 2.2):
     signal stay device-resident for the whole chunk, so the host pays
     one dispatch + one sync per N ticks instead of per tick.
 
-The distributed engine (``core/distributed.py``) runs this same tick
-per-shard under ``shard_map`` with an all_to_all key-routing exchange in
-front of every enqueue.
+The tick itself is ``core/tick.py``'s ``tick``, the one function both
+engines run.  This engine jits it with no ``send`` hook; the distributed
+engine (``core/distributed.py``) runs it per shard under ``shard_map``
+and passes the ring route and all_to_all exchange as ``send``, the one
+seam between them, so the sharded tick carries the same named scopes.
 """
 from __future__ import annotations
 
 import threading
 import time
-from collections import deque
 from contextlib import nullcontext
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import apply as apply_mod
 from repro.core import queues as q_mod
+from repro.core import tick as tick_mod
 from repro.core.durability import DurabilityConfig, EngineDurability
-from repro.core.event import EventBatch, concat
-from repro.core.operators import (AssociativeUpdater, Mapper,
-                                  SequentialUpdater, Updater)
+from repro.core.event import EventBatch
 from repro.core.queues import OverflowPolicy
 from repro.core.workflow import Workflow
 from repro.slates import flush as flush_mod
@@ -241,6 +240,7 @@ class Engine:
                               static_argnames=("n_ticks", "adapt",
                                                "throttle_floor"))
         self.telemetry: Optional[MetricsRegistry] = None
+        self._salts = None
         self.tracer: Optional[Tracer] = tracer_for(self.cfg.telemetry)
         self.dur: Optional[EngineDurability] = None
         if self.cfg.durability is not None:
@@ -294,129 +294,8 @@ class Engine:
 
     # ---- one tick (jit) ----
     def _tick(self, state, sources: Dict[str, EventBatch]):
-        cfg, wf = self.cfg, self.wf
-        queues = dict(state["queues"])
-        tables = dict(state["tables"])
-        processed = dict(state["processed"])
-        throttle_hits = state["throttle_hits"]
-        deferred_total = state["deferred"]
-        tick = state["tick"]
-        sketch = state.get("sketch")
-        lat_hist = dict(state["lat_hist"]) if "lat_hist" in state \
-            else None
-        outputs: Dict[str, List[EventBatch]] = {}
-
-        def deliver_all(items: List[Tuple[str, EventBatch]]):
-            """Route batches to subscriber queues; overflow-stream policy
-            may chain (bounded — cycles are a config error)."""
-            nonlocal throttle_hits
-            work = deque(items)
-            for _ in range(len(work) + 64):
-                if not work:
-                    return
-                stream, batch = work.popleft()
-                subs = wf.dests_of(stream)
-                if not subs:
-                    outputs.setdefault(stream, []).append(batch)
-                    continue
-                for dest in subs:
-                    nq, ovf = q_mod.enqueue(queues[dest], batch)
-                    pol = cfg.policy_for(dest)
-                    if pol is OverflowPolicy.DROP:
-                        nq = q_mod.count_drop(nq, ovf)
-                    elif pol is OverflowPolicy.OVERFLOW_STREAM:
-                        work.append((cfg.overflow_stream[dest], ovf))
-                    elif pol is OverflowPolicy.THROTTLE:
-                        throttle_hits = throttle_hits + ovf.count()
-                        nq = q_mod.count_drop(nq, ovf)
-                    queues[dest] = nq
-            raise RuntimeError("overflow-stream routing did not converge "
-                               "(cycle in overflow_stream config?)")
-
-        # The tick's phases carry named scopes (DESIGN.md 18.2): they
-        # name the device operations of each phase in the compiled
-        # program's metadata and the profiler's trace, and change no
-        # computation.  The updaters' phases are scoped in core/apply.
-        # 1. deliver sources (visible to operators this tick; operator
-        #    emissions become visible next tick — pipelined execution).
-        with jax.named_scope("tick.queues"):
-            deliver_all(list(sources.items()))
-        emitted_now: List[Tuple[str, EventBatch]] = []
-
-        # 2. apply operators on their queues
-        for op in wf.operators:
-            with jax.named_scope("tick.queues"):
-                queues[op.name], batch = q_mod.dequeue(queues[op.name],
-                                                       cfg.batch_size)
-            if sketch is not None and isinstance(op, Updater):
-                # key-heat telemetry: observe the keys each updater
-                # actually processes (post-routing) — pure extra state,
-                # never read by the tick itself (parity contract)
-                with jax.named_scope("tick.telemetry"):
-                    sketch = sk_mod.sketch_update(
-                        sketch, batch.key, batch.valid, self._salts,
-                        impl=cfg.telemetry.impl)
-            if lat_hist is not None and isinstance(op, Updater):
-                # event-latency telemetry (DESIGN.md 18): the event's
-                # age at dequeue, binned into this arc's power-of-two
-                # histogram — same parity contract as the sketch
-                with jax.named_scope("tick.telemetry"):
-                    lat_hist[op.name] = lat_mod.hist_update(
-                        lat_hist[op.name], tick, batch.ts, batch.valid,
-                        n_buckets=cfg.telemetry.latency_buckets,
-                        impl=cfg.telemetry.impl)
-            if isinstance(op, Mapper):
-                with jax.named_scope("tick.map"):
-                    outs = op.map_batch(batch)
-                    for s, b in outs.items():
-                        emitted_now.append(
-                            (s, b.mask(batch.valid & b.valid)))
-                processed[op.name] = processed[op.name] + batch.count()
-            elif isinstance(op, AssociativeUpdater):
-                tables[op.name], ems, n = apply_mod.apply_associative(
-                    op, tables[op.name], batch, tick, impl=cfg.fused)
-                emitted_now.extend(ems.items())
-                processed[op.name] = processed[op.name] + n
-            elif isinstance(op, SequentialUpdater):
-                tables[op.name], ems, deferred, n = \
-                    apply_mod.apply_sequential(op, tables[op.name], batch,
-                                               tick)
-                emitted_now.extend(ems.items())
-                # hotspot backpressure: re-queue over-budget run tails
-                deferred_total = deferred_total + deferred.count()
-                with jax.named_scope("tick.queues"):
-                    nq, ovf = q_mod.enqueue(queues[op.name], deferred)
-                    queues[op.name] = q_mod.count_drop(nq, ovf)
-                processed[op.name] = processed[op.name] + n
-            else:
-                raise TypeError(f"unknown operator type {type(op)}")
-
-        # 3. TTL sweeps
-        for up in wf.updaters():
-            if up.ttl:
-                with jax.named_scope("apply.write"):
-                    tables[up.name] = tbl.expire_ttl(tables[up.name],
-                                                     tick, up.ttl)
-
-        # 4. route this tick's emissions (visible next tick)
-        with jax.named_scope("tick.queues"):
-            deliver_all(emitted_now)
-
-        out_batches = {s: concat(bs) if len(bs) > 1 else bs[0]
-                       for s, bs in outputs.items()}
-        new_state = {
-            "queues": queues,
-            "tables": tables,
-            "tick": tick + 1,
-            "throttle_hits": throttle_hits,
-            "deferred": deferred_total,
-            "processed": processed,
-        }
-        if sketch is not None:
-            new_state["sketch"] = sketch
-        if lat_hist is not None:
-            new_state["lat_hist"] = lat_hist
-        return new_state, out_batches
+        return tick_mod.tick(self.wf, self.cfg, self.cfg.telemetry,
+                             self._salts, state, sources)
 
     # ---- multi-tick chunk (jit: one dispatch, one sync per chunk) ----
     def _chunk_impl(self, state, stacked_sources, ingest, *,
@@ -636,14 +515,16 @@ class Engine:
     def _drain_queues(self, state, max_ticks: int):
         """Run source-less ticks until every queue is empty — the flush
         barrier.  Each probe costs one host sync; barriers are rare
-        (flush boundaries only).  Returns (state, ticks_run)."""
+        (flush boundaries only).  A queue's size sums over its shards,
+        so ``DistributedEngine`` drains with this same method.  Returns
+        (state, ticks_run)."""
         d = 0
         while d < max_ticks:
             sizes = jax.device_get({k: q.size
                                     for k, q in state["queues"].items()})
-            if all(int(v) == 0 for v in sizes.values()):
+            if all(int(np.sum(v)) == 0 for v in sizes.values()):
                 break
-            state, _ = self._step(state, {})
+            state, _ = self.step(state, {})
             d += 1
         return state, d
 

@@ -347,9 +347,10 @@ TICK_SCOPES = ("tick.queues", "tick.telemetry", "tick.map", "apply.sort",
                "apply.probe", "apply.pack", "apply.write")
 
 
-def _counting_app(capacity=256, batch=32, fused="ref"):
+def _counting_app(capacity=256, batch=32, fused="ref", mesh=None):
     """Paper Examples 1/4 at a tiny size: a mapper, a counter and an
-    [8]-lane sum, both on the packed path."""
+    [8]-lane sum, both on the packed path; sharded over ``mesh`` when
+    one is given."""
     from repro import App, EventBatch, RuntimeConfig, ops
     app = App("scoped")
     checkins = app.source("S1", {"x": ((), jnp.float32)})
@@ -368,7 +369,8 @@ def _counting_app(capacity=256, batch=32, fused="ref"):
         return {"v": jnp.ones((b.key.shape[0], 8), jnp.float32)}
 
     app.start(RuntimeConfig(batch_size=batch, chunk_size=2, fused=fused,
-                            telemetry=TelemetryConfig(impl="jnp")))
+                            telemetry=TelemetryConfig(impl="jnp"),
+                            mesh=mesh))
 
     def source(t, _mx=None):
         return {"S1": EventBatch(
@@ -380,18 +382,37 @@ def _counting_app(capacity=256, batch=32, fused="ref"):
     return app, source
 
 
-def test_tick_phases_are_named_in_the_compiled_chunk():
+def _lowered_tick(program, cap):
+    """The counting app's tick program, lowered: the single-shard chunk,
+    or the sharded step or chunk on a one-device mesh."""
+    if program == "chunk":
+        app, source = _counting_app(capacity=cap)
+        eng = app.engine
+        return eng._chunk.lower(
+            eng.init_state(), stack_sources([source(0), source(1)]),
+            jnp.int32(32), n_ticks=2, adapt=False, throttle_floor=8)
+    from jax.sharding import Mesh
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    app, source = _counting_app(capacity=cap, mesh=mesh)
+    eng = app.engine
+    if program == "sharded-step":
+        src = jax.tree.map(lambda x: x[None], source(0))
+        return eng._step.lower(eng.init_state(), src, *eng._tick_inputs())
+    src = jax.tree.map(lambda x: x[:, None],
+                       stack_sources([source(0), source(1)]))
+    return eng._chunk.lower(eng.init_state(), src, *eng._tick_inputs())
+
+
+@pytest.mark.parametrize("program", ["chunk", "sharded-step",
+                                     "sharded-chunk"])
+def test_tick_phases_are_named_in_the_compiled_chunk(program):
     """Every phase of the tick carries its named scope into the compiled
-    chunk's op_name metadata, and every claim scatter of
+    program's op_name metadata, on one shard and on many (both engines
+    run one tick function), and every claim scatter of
     ``insert_or_find`` (one per updater, in the body of its claim-round
     loop, into the s32[C] key array) lies under ``apply.probe``."""
     cap = 256
-    app, source = _counting_app(capacity=cap)
-    eng = app.engine
-    hlo = eng._chunk.lower(
-        eng.init_state(), stack_sources([source(0), source(1)]),
-        jnp.int32(32), n_ticks=2, adapt=False,
-        throttle_floor=8).compile().as_text()
+    hlo = _lowered_tick(program, cap).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', hlo)
     for scope in TICK_SCOPES:
         assert any(f"/{scope}/" in n for n in names), scope
